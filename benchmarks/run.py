@@ -23,6 +23,9 @@ def main(argv=None) -> int:
                     help="run only the CI-gated smoke suites (skip the "
                          "paper-figure measurement suites)")
     args = ap.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     rows = []
     failed = []
     from . import (

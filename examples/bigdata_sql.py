@@ -14,6 +14,7 @@ import time
 import numpy as np
 
 from repro import MapReduceSpec, Session
+from repro.compile_cache import use_compile_cache
 from repro.sched.fault_tolerant import HybridFaultTolerantScheduler, verify_coverage
 
 
@@ -23,6 +24,7 @@ def main() -> None:
     ap.add_argument("--planner", choices=["cost", "none"], default="cost")
     ap.add_argument("--explain", action="store_true", help="print full EXPLAIN per query")
     args = ap.parse_args()
+    use_compile_cache()
 
     rng = np.random.default_rng(0)
     n = args.rows

@@ -12,9 +12,11 @@
 import numpy as np
 
 from repro import MapReduceSpec, Session
+from repro.compile_cache import use_compile_cache
 
 
 def main() -> None:
+    use_compile_cache()
     # --- some web-access data (strings! the compiler will reformat) -------
     rng = np.random.default_rng(0)
     urls = np.array([f"http://site{i % 23}.com/p{i % 7}" for i in rng.integers(0, 2000, 50_000)], dtype=object)

@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs.base import get_config, reduced_config
 from repro.models.transformer import Model, prefill_forward
 from repro.serve.kvcache import cache_bytes, dequantize_kv, quantize_kv
@@ -22,6 +23,7 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--new", type=int, default=32)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = reduced_config(get_config(args.arch))
     model = Model(cfg)

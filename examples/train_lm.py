@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs.base import get_config, reduced_config
 from repro.data.pipeline import PipelineConfig, ShardedLoader, build_dataset
 from repro.models.transformer import Model
@@ -48,6 +49,7 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default="runs/ckpt_train_lm")
     ap.add_argument("--fail-at-step", type=int, default=-1, help="simulate worker failure")
     args = ap.parse_args()
+    use_compile_cache()
 
     # --- data: the forelem pipeline ----------------------------------------
     print("building dataset through the forelem pipeline ...")

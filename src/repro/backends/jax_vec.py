@@ -58,7 +58,8 @@ class CodegenChoices:
                 'onehot'  — one-hot × MXU matmul histogram,
                 'sort'    — sort + segment reduction (tree-index analogue),
                 'kernel'  — Pallas segreduce kernel (VMEM-resident
-                             accumulator; interpret-mode on CPU).
+                             accumulator; Mosaic-compiled on TPU, the jnp
+                             fused fallback elsewhere).
     parallel:   'none'    — single-program,
                 'vmap'    — N-way partitioned execution emulated with vmap
                              (semantics of the forall on one device),
@@ -579,12 +580,8 @@ class JaxLowering:
                 return partials.sum(0)
             return partials.max(0) if op == "max" else partials.min(0)
         if c.parallel == "shard_map":
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
-
-            try:  # jax ≥ 0.5 exports it at top level
-                from jax import shard_map
-            except ImportError:  # 0.4.x
-                from jax.experimental.shard_map import shard_map
 
             mesh = c.mesh
             if mesh is None:
@@ -608,7 +605,12 @@ class JaxLowering:
                     raise UnsupportedProgram(f"shard_map op {op}")
                 return acc[None]
 
-            f = shard_map(local, mesh=mesh, in_specs=(P(ax), P(ax)), out_specs=P(ax))
+            # check_vma=False: a pallas_call's out shapes carry no
+            # varying-axes type, so the checked mode cannot type the
+            # segreduce kernel; the combine above is explicit anyway
+            f = shard_map(
+                local, mesh=mesh, in_specs=(P(ax), P(ax)), out_specs=P(ax), check_vma=False
+            )
             res = f(keys, values)
             return res[0]
         raise ValueError(f"bad parallel {c.parallel}")
@@ -664,15 +666,16 @@ class JaxLowering:
             lo = jnp.minimum(lo, n_valid_build)
             hi = jnp.minimum(hi, n_valid_build)
         counts = hi - lo
-        slots = jnp.arange(mult)
-        pos = jnp.clip(lo[:, None] + slots[None, :], 0, sk.shape[0] - 1)  # (n_probe, M)
-        present = slots[None, :] < counts[:, None]
+        # the (probe_rows × M) slot space, probe-row-major, built 1-D: an
+        # (n_probe, M) array with a small M is padded to 128 lanes on a TPU
+        slot_ids = jnp.arange(n_probe * mult, dtype=jnp.int32)
+        probe_idx = slot_ids // mult
+        slot = slot_ids - probe_idx * mult
+        pos = jnp.clip(lo[probe_idx] + slot, 0, sk.shape[0] - 1)
+        present = slot < counts[probe_idx]
         if pmask is not None:
-            present = present & pmask[:, None]
-        probe_idx = jnp.broadcast_to(
-            jnp.arange(n_probe, dtype=jnp.int32)[:, None], (n_probe, mult)
-        ).reshape(-1)
-        return _JoinRows(probe_idx, order[pos.reshape(-1)], present.reshape(-1), False)
+            present = present & pmask[probe_idx]
+        return _JoinRows(probe_idx, order[pos], present, False)
 
     def _join_gather(self, e: Expr, j: JoinSpec, jr: "_JoinRows", cols):
         """Vectorize an expression over the joined (probe, build) row pairs."""

@@ -1,6 +1,11 @@
 # Jitted public wrappers for the segreduce kernels, and the REPRO_PALLAS
 # execution-mode knob the query engine (and the planner's cost model)
 # resolves the Pallas-vs-jnp decision through.
+#
+# On a TPU the engine always runs the Mosaic-compiled kernel: asking for the
+# jnp fallback there raises instead of quietly running something else.  Off
+# TPU the kernel can only be interpreted (slow, for correctness tests), so
+# the default there is the jnp fallback.
 from __future__ import annotations
 
 import os
@@ -12,6 +17,14 @@ import jax
 from .kernel import fused_segreduce_pallas, segreduce_pallas
 from .ref import fused_segreduce_ref, segreduce_ref
 
+# rows one grid step of the engine's kernel launches streams (512 lane rows
+# of 128, 256 KiB per 4-byte column): on a v5e a 3000-key COUNT over 2^25
+# rows ran faster at 2^16 than at 2^14
+ENGINE_TILE = 1 << 16
+
+_OFF = ("0", "off", "never", "jnp")
+_FORCE = ("1", "on", "force", "interpret")
+
 
 def pallas_mode() -> str:
     """How the segmented-aggregation kernels execute, resolved from the
@@ -20,18 +33,27 @@ def pallas_mode() -> str:
       * ``'compiled'``  — real Pallas kernel, Mosaic-compiled (TPU),
       * ``'interpret'`` — Pallas kernel in interpret mode (slow; only when
         forced off-TPU with ``REPRO_PALLAS=1`` — correctness testing),
-      * ``'off'``       — the pure-jnp fused fallback (``ref.py``).
+      * ``'off'``       — the pure-jnp fused fallback (``ref.py``), off-TPU
+        only.
 
     Unset / ``auto``: compiled on TPU, fallback elsewhere.  ``1``/``force``
-    runs the Pallas kernel even off-TPU (interpret mode); ``0``/``off``
-    always uses the jnp fallback.  The knob is read at trace time — an
+    runs the Pallas kernel even off-TPU (interpret mode).  ``0``/``off``
+    selects the jnp fallback off-TPU and raises on a TPU, where the kernel
+    is the engine's path.  The knob is read at trace time — an
     already-jitted caller keeps the mode it compiled with."""
     env = os.environ.get("REPRO_PALLAS", "auto").strip().lower()
     on_tpu = jax.default_backend() == "tpu"
-    if env in ("0", "off", "never", "jnp"):
+    if env in _OFF:
+        if on_tpu:
+            raise RuntimeError(
+                f"REPRO_PALLAS={env!r} asks for the jnp fallback on a TPU; the "
+                "engine runs the compiled segreduce kernel there — unset it"
+            )
         return "off"
-    if env in ("1", "on", "force", "interpret"):
+    if env in _FORCE:
         return "compiled" if on_tpu else "interpret"
+    if env not in ("auto", ""):
+        raise ValueError(f"unknown REPRO_PALLAS value {env!r}")
     return "compiled" if on_tpu else "off"
 
 
@@ -47,7 +69,9 @@ def _resolve_mode(use_pallas: Optional[bool]) -> str:
 def _segreduce_impl(keys, values, num_keys: int, op: str, mode: str):
     if mode == "off":
         return segreduce_ref(keys, values, num_keys, op)
-    return segreduce_pallas(keys, values, num_keys, op, interpret=(mode == "interpret"))
+    return segreduce_pallas(
+        keys, values, num_keys, op, tile=ENGINE_TILE, interpret=(mode == "interpret")
+    )
 
 
 def segreduce(keys, values, num_keys: int, op: str = "sum", use_pallas: Optional[bool] = None):
@@ -65,7 +89,7 @@ def _fused_impl(keys, values, mask, ops, num_keys: int, with_presence: bool, mod
         )
     return fused_segreduce_pallas(
         keys, values, ops, num_keys, mask=mask,
-        with_presence=with_presence, interpret=(mode == "interpret"),
+        with_presence=with_presence, tile=ENGINE_TILE, interpret=(mode == "interpret"),
     )
 
 

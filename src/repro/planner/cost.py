@@ -4,15 +4,17 @@
 #       dense   — scatter/segment_sum into a dense accumulator,
 #       onehot  — one-hot × MXU matmul histogram (rows × keys work!),
 #       sort    — argsort + sorted segment reduction,
-#       kernel  — Pallas segreduce (VMEM accumulator; *interpret mode* on
-#                 CPU, which is orders of magnitude slower — the backend
-#                 term below is what keeps the planner honest about it),
+#       kernel  — Pallas segreduce (VMEM accumulator): Mosaic-compiled on
+#                 TPU; off TPU the pure-jnp fused fallback, or interpret mode
+#                 (orders of magnitude slower) when REPRO_PALLAS=1 forces it
+#                 — the per-element rate follows the mode that will run,
 #   * parallel execution of foralls: none / vmap / shard_map,
 #   * partition-field choice for indirect partitioning (skew-aware).
 #
 # Units are abstract "element-ops" (1.0 ≈ one streaming element visit).
 # The default coefficients were fitted against bench_fig2-style
-# microbenchmarks on the CPU backend; ``calibrate()`` re-measures them on
+# microbenchmarks on the CPU backend (``c_kernel``, the compiled TPU rate,
+# has not been fitted on a chip yet); ``calibrate()`` re-measures them on
 # the current machine (used by benchmarks/bench_planner.py).
 from __future__ import annotations
 
@@ -37,9 +39,9 @@ class CostCoefficients:
     c_dense: float = 2.5         # scatter-add per element
     c_onehot: float = 0.08       # per cell of the rows×keys one-hot matmul
     c_sort: float = 1.2          # per element per log2(rows) of argsort
-    c_kernel: float = 2.0        # per element inside the Pallas kernel
-    c_kernel_interpret: float = 400.0  # ... in interpret mode (forced off-TPU)
-    c_kernel_fallback: float = 2.2     # ... in the pure-jnp fused fallback
+    c_kernel: float = 2.0        # per element inside the compiled kernel (TPU)
+    c_kernel_interpret: float = 400.0  # ... in interpret mode (REPRO_PALLAS=1 off TPU)
+    c_kernel_fallback: float = 2.2     # ... in the pure-jnp fused fallback (off TPU)
     c_kernel_fixed: float = 2e4  # kernel launch / trace overhead
     c_kernel_agg: float = 0.7    # per element per EXTRA fused aggregate —
     #                              another accumulator update inside the one
@@ -70,19 +72,10 @@ class CostModel:
         self,
         stats: DbStats,
         coeffs: Optional[CostCoefficients] = None,
-        backend: Optional[str] = None,
         profile: Optional[ObservedProfile] = None,
     ):
         self.stats = stats
         self.coeffs = coeffs or default_coefficients()
-        if backend is None:
-            try:
-                import jax
-
-                backend = jax.default_backend()
-            except Exception:
-                backend = "cpu"
-        self.backend = backend
         self.profile = profile
         self.est = CardinalityEstimator(stats, profile)
 
@@ -90,12 +83,13 @@ class CostModel:
     def _kernel_per_elem(self) -> float:
         """Per-element cost of the segreduce kernel path under the mode the
         runtime will actually execute (kernels/segreduce/ops.pallas_mode):
-        Mosaic-compiled on TPU/GPU, the pure-jnp fused fallback off-device,
-        or interpret mode when REPRO_PALLAS forces the kernel off-TPU."""
+        Mosaic-compiled on TPU, the pure-jnp fused fallback elsewhere, or
+        interpret mode when REPRO_PALLAS forces the kernel off-TPU."""
         c = self.coeffs
-        if self.backend in ("tpu", "gpu"):
+        mode = pallas_mode()
+        if mode == "compiled":
             return c.c_kernel
-        return c.c_kernel_interpret if pallas_mode() == "interpret" else c.c_kernel_fallback
+        return c.c_kernel_interpret if mode == "interpret" else c.c_kernel_fallback
 
     def agg_cost(self, rows: float, num_keys: float, method: str, op: str) -> float:
         c = self.coeffs

@@ -136,7 +136,6 @@ def enumerate_candidates(
     n_parts: int = 1,
     coeffs: Optional[CostCoefficients] = None,
     allow_shard_map: bool = False,
-    backend: Optional[str] = None,
     executor: Optional[str] = None,
     n_partitions: Optional[int] = None,
     schedule: Optional[str] = None,
@@ -160,7 +159,7 @@ def enumerate_candidates(
 
     ``profile`` (planner/feedback.py) substitutes measured selectivity /
     row skew / jit hit rate for the static-stats estimates when pricing."""
-    model = CostModel(stats, coeffs, backend=backend, profile=profile)
+    model = CostModel(stats, coeffs, profile=profile)
     orders: List[Tuple[str, Program]] = [("as-written", program)]
     for k, variant in enumerate(T.join_orders(program)):
         orders.append((f"interchanged[{k}]", variant))
@@ -308,7 +307,6 @@ def plan_query(
     n_parts: int = 1,
     coeffs: Optional[CostCoefficients] = None,
     allow_shard_map: bool = False,
-    backend: Optional[str] = None,
     executor: Optional[str] = None,
     n_partitions: Optional[int] = None,
     schedule: Optional[str] = None,
@@ -326,7 +324,7 @@ def plan_query(
     try:
         cands = enumerate_candidates(
             program, stats, n_parts, coeffs, allow_shard_map=allow_shard_map,
-            backend=backend, executor=executor, n_partitions=n_partitions, schedule=schedule,
+            executor=executor, n_partitions=n_partitions, schedule=schedule,
             rejections=rejections, profile=profile,
         )
         chosen = cands[0]

@@ -87,6 +87,59 @@ if HAVE_HYPOTHESIS:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("dtype", ["int32", "float32"])
+@pytest.mark.parametrize("tile", [2048, 8192])
+def test_fused_blocks_groups_and_key_tiles(rng, dtype, tile):
+    """Several row blocks, several (8, 128) row groups per block, several
+    key tiles and a ragged tail: every grid and loop boundary of the
+    lane-dense kernel against the jnp fallback."""
+    n, num_keys = 20_000, 300
+    keys = rng.integers(-2, num_keys + 2, n).astype(np.int32)  # out-of-range keys hit nothing
+    if dtype == "int32":
+        vals = rng.integers(-1000, 1000, n).astype(np.int32)
+    else:
+        vals = rng.normal(size=n).astype(np.float32)
+    mask = rng.integers(0, 3, n) > 0
+    ops = ("sum", "max", "min")
+    got, got_pres = fused_segreduce_pallas(
+        jnp.asarray(keys), (jnp.asarray(vals),) * 3, ops, num_keys,
+        mask=jnp.asarray(mask), tile=tile, interpret=True,
+    )
+    inside = (keys >= 0) & (keys < num_keys)
+    want, want_pres = fused_segreduce_ref(
+        jnp.asarray(np.where(inside, keys, 0)), (jnp.asarray(vals),) * 3, ops, num_keys,
+        mask=jnp.asarray(mask & inside),
+    )
+    np.testing.assert_array_equal(np.asarray(got_pres), np.asarray(want_pres))
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize(
+    "backend,env,want",
+    [
+        ("tpu", "auto", "compiled"),
+        ("tpu", "1", "compiled"),
+        ("tpu", "0", RuntimeError),  # no silent jnp fallback on the chip
+        ("cpu", "auto", "off"),
+        ("cpu", "1", "interpret"),
+        ("cpu", "0", "off"),
+        ("cpu", "bogus", ValueError),
+    ],
+)
+def test_pallas_mode(monkeypatch, backend, env, want):
+    from repro.kernels.segreduce import ops
+
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    monkeypatch.setenv("REPRO_PALLAS", env)
+    if isinstance(want, str):
+        assert ops.pallas_mode() == want
+    else:
+        with pytest.raises(want):
+            ops.pallas_mode()
+
+
 # ---------------------------------------------------------------------------
 # fused multi-aggregate segreduce: the differential matrix
 #

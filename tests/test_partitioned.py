@@ -350,3 +350,20 @@ def test_shard_map_minmax_fixed(rng, agg):
     p = sql_to_forelem(f"SELECT k, {agg}(v) FROM t GROUP BY k", {"t": ["k", "v"]})
     res = optimize(p, db, OptimizeOptions(n_parts=4, parallel_exec="shard_map", mesh=mesh))
     assert sorted(res.plan.run()["R"]) == ref_rows(p, db)
+
+
+@pytest.mark.parametrize("agg", ["MAX", "SUM", "COUNT"])
+def test_shard_map_segreduce_kernel(rng, monkeypatch, agg):
+    """agg_method='kernel' under shard_map: the Pallas kernel (interpret
+    mode here) runs inside the SPMD body and its partials combine."""
+    monkeypatch.setenv("REPRO_PALLAS", "1")
+    k = rng.integers(0, 70, 3001).astype(np.int32)
+    v = rng.integers(-80, 80, 3001).astype(np.int32)
+    db = Database().add(Multiset.from_columns("t", k=k, v=v))
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    p = sql_to_forelem(f"SELECT k, {agg}(v) FROM t GROUP BY k", {"t": ["k", "v"]})
+    res = optimize(
+        p, db,
+        OptimizeOptions(n_parts=4, agg_method="kernel", parallel_exec="shard_map", mesh=mesh),
+    )
+    assert sorted(res.plan.run()["R"]) == ref_rows(p, db)
