@@ -16,14 +16,17 @@ def use_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache and return its directory.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and
-    nothing is set here.  Otherwise the cache goes to ``.jax_cache/`` in the
-    checkout, keeping every entry: the engine's kernels compile in well
-    under JAX's default one-second threshold, but there are many of them."""
+    the directory is left as it is; otherwise the cache goes to
+    ``.jax_cache/`` in the checkout.  Either way every entry is kept, unless
+    ``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS`` says otherwise: the
+    engine's kernels compile in well under JAX's default one-second
+    threshold, but there are many of them."""
+    import jax
+
+    if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_CACHE_DIR))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return str(CHECKOUT_CACHE_DIR)

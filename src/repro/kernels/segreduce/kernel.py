@@ -20,14 +20,16 @@
 # Layout: every column is viewed lane-dense as (rows, 128) — one input block
 # is (block_rows, 128), so HBM and VMEM hold the data at its own size (an
 # (N, 1) column would take a whole 128-lane row per element).  Key ids run
-# along sublanes: an accumulator block is (KEY_TILE, 128), one key id per
-# row and one lane-partial per lane; the wrapper reduces the 128 lane
-# partials at the end.  The grid is (key tiles, row blocks): the row axis is
-# the reduction, so each key tile's accumulators stay resident in VMEM while
-# every row block streams past them (TPU grids run sequentially, so the
-# read-modify-write accumulation is race-free).  Integer and float
-# accumulators are preserved; sub-f32 floats accumulate in f32 and are cast
-# back.
+# along sublanes: a VMEM accumulator is (KEY_TILE, 128), one key id per row
+# and one lane partial per lane.  The grid is (key tiles, row blocks): the
+# row axis is the reduction, so each key tile's accumulators stay resident
+# while every row block streams past them (TPU grids run sequentially, so
+# the read-modify-write accumulation is race-free).  After the last row
+# block the kernel folds the lane partials and writes the tile's keys along
+# the lanes of a dense (keys / 128, 128) output, 4 bytes a key.  The work is
+# rows x key tiles: the planner prices it so (planner/cost.py).  Integer and
+# float accumulators are preserved; sub-f32 floats accumulate in f32 and are
+# cast back.
 from __future__ import annotations
 
 import functools
@@ -36,6 +38,7 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Ops the segmented-aggregation kernels evaluate (engine spelling '+' is
 # mapped to 'sum' by backends/jax_vec).  COUNT and AVG lower to these at
@@ -73,7 +76,10 @@ _LANES = 128
 _SUBLANES = 8
 # key ids per accumulator block (8 vregs of (8, 128) per accumulator): on a
 # v5e, 64 ran a 3000-key COUNT over 2^25 rows faster than 32 or 128
+# (benchmarks/chip_segreduce.py).  Every key tile streams all the rows once.
 KEY_TILE = 64
+_TILES_PER_ROW = _LANES // KEY_TILE  # key tiles per 128-key output row
+_TILES_PER_OUT = _TILES_PER_ROW * _SUBLANES  # ... per (8, 128) output block
 
 
 def _combine(op: str, acc, x):
@@ -82,24 +88,34 @@ def _combine(op: str, acc, x):
     return jnp.maximum(acc, x) if op == "max" else jnp.minimum(acc, x)
 
 
-def _fused_kernel(*refs, ops: Tuple[str, ...], with_presence: bool, n_groups: int):
-    n_aggs = len(ops)
+def _reduce(op: str, x: jnp.ndarray, axis: int) -> jnp.ndarray:
+    if op == "sum":
+        return x.sum(axis=axis, keepdims=True)
+    return (x.max if op == "max" else x.min)(axis=axis, keepdims=True)
+
+
+def _fused_kernel(*refs, ops: Tuple[str, ...], n_vals: int, n_groups: int):
+    # ``ops`` holds every accumulator's op: the first ``n_vals`` fold a value
+    # column, a last extra one (presence, a sum) counts the hits
+    n_accs = len(ops)
     keys_ref = refs[0]
-    vals_refs = refs[1 : 1 + n_aggs]
-    acc_refs = refs[1 + n_aggs :]  # one per aggregate, then presence
-    key_tile = acc_refs[0].shape[0]
+    vals_refs = refs[1 : 1 + n_vals]
+    out_refs = refs[1 + n_vals : 1 + n_vals + n_accs]
+    acc_refs = refs[1 + n_vals + n_accs :]  # VMEM lane partials, (KEY_TILE, 128)
+    j, i = pl.program_id(0), pl.program_id(1)
+    idents = [op_identity(op, a.dtype) for op, a in zip(ops, acc_refs)]
 
-    @pl.when(pl.program_id(1) == 0)
+    @pl.when(i == 0)
     def _init():
-        for op, o_ref in zip(ops, acc_refs):
-            o_ref[...] = jnp.full_like(o_ref, op_identity(op, o_ref.dtype))
-        if with_presence:
-            acc_refs[n_aggs][...] = jnp.zeros_like(acc_refs[n_aggs])
+        for a_ref, ident in zip(acc_refs, idents):
+            a_ref[...] = jnp.full_like(a_ref, ident)
 
-    key_ids = pl.program_id(0) * key_tile + jax.lax.broadcasted_iota(
-        jnp.int32, (key_tile, _LANES), 0
-    )
-    idents = [op_identity(op, o_ref.dtype) for op, o_ref in zip(ops, acc_refs)]
+    @pl.when((i == 0) & (j % _TILES_PER_OUT == 0))
+    def _init_out():
+        for o_ref, ident in zip(out_refs, idents):
+            o_ref[...] = jnp.full_like(o_ref, ident)
+
+    key_ids = j * KEY_TILE + jax.lax.broadcasted_iota(jnp.int32, (KEY_TILE, _LANES), 0)
 
     def group(g, accs):
         # one aligned (8, 128) load per column, then its 8 rows one by one:
@@ -109,23 +125,32 @@ def _fused_kernel(*refs, ops: Tuple[str, ...], with_presence: bool, n_groups: in
         vals = [v_ref[pl.ds(r0, _SUBLANES), :] for v_ref in vals_refs]
         accs = list(accs)
         for s in range(_SUBLANES):
-            hit = key_ids == keys[s : s + 1, :]  # (key_tile, 128)
-            for i, op in enumerate(ops):
-                contrib = jnp.where(hit, vals[i][s : s + 1, :], idents[i])
-                accs[i] = _combine(op, accs[i], contrib)
-            if with_presence:
-                accs[n_aggs] = accs[n_aggs] + jnp.where(hit, 1, 0).astype(jnp.int32)
+            hit = key_ids == keys[s : s + 1, :]  # (KEY_TILE, 128)
+            for a in range(n_vals):
+                contrib = jnp.where(hit, vals[a][s : s + 1, :], idents[a])
+                accs[a] = _combine(ops[a], accs[a], contrib)
+            if n_accs > n_vals:
+                accs[n_vals] = accs[n_vals] + jnp.where(hit, 1, 0).astype(jnp.int32)
         return tuple(accs)
 
-    accs = jax.lax.fori_loop(0, n_groups, group, tuple(r[...] for r in acc_refs))
-    for r, a in zip(acc_refs, accs):
-        r[...] = a
+    accs = jax.lax.fori_loop(0, n_groups, group, tuple(a[...] for a in acc_refs))
+    for a_ref, a in zip(acc_refs, accs):
+        a_ref[...] = a
 
-
-def _lane_reduce(op: str, acc: jnp.ndarray) -> jnp.ndarray:
-    if op == "sum":
-        return acc.sum(axis=1)
-    return acc.max(axis=1) if op == "max" else acc.min(axis=1)
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _flush():
+        # fold each key's 128 lane partials, then move the key tile from
+        # sublanes onto lanes: key k of tile j lands in lane
+        # (j % _TILES_PER_ROW) * KEY_TILE + k of output row j // _TILES_PER_ROW
+        sub = jax.lax.broadcasted_iota(jnp.int32, (KEY_TILE, _LANES), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (KEY_TILE, _LANES), 1)
+        on_lane = lane == sub + (j % _TILES_PER_ROW) * KEY_TILE
+        out_row = jax.lax.broadcasted_iota(jnp.int32, (_SUBLANES, _LANES), 0)
+        on_row = out_row == (j // _TILES_PER_ROW) % _SUBLANES
+        for op, a, o_ref, ident in zip(ops, accs, out_refs, idents):
+            per_key = _reduce(op, a, axis=1)  # (KEY_TILE, 1)
+            row = _reduce(op, jnp.where(on_lane, per_key, ident), axis=0)  # (1, 128)
+            o_ref[...] = _combine(op, o_ref[...], jnp.where(on_row, row, ident))
 
 
 def fused_segreduce_pallas(
@@ -177,28 +202,27 @@ def fused_segreduce_pallas(
         keys = jnp.where(mask.astype(bool), keys, -1)  # -1 matches no key id
     keys_2d = lane_dense(keys, -1)  # padding rows are masked rows
     vals_2d = [lane_dense(v.astype(dt), 0) for v, dt in zip(values, dts)]
-    kp = num_keys + ((-num_keys) % KEY_TILE)
+    n_tiles = -(-num_keys // KEY_TILE)
+    out_rows = -(-n_tiles // _TILES_PER_OUT) * _SUBLANES
+    acc_ops = tuple(ops) + (("sum",) if with_presence else ())
     acc_dts = dts + ([jnp.dtype(jnp.int32)] if with_presence else [])
     row_block = pl.BlockSpec((block_rows, _LANES), lambda j, i: (i, 0))
-    acc_block = pl.BlockSpec((KEY_TILE, _LANES), lambda j, i: (j, 0))
+    # one (8, 128) output block holds the keys of _TILES_PER_OUT key tiles,
+    # so it stays resident while they run and is written back once
+    out_block = pl.BlockSpec((_SUBLANES, _LANES), lambda j, i: (j // _TILES_PER_OUT, 0))
     outs = pl.pallas_call(
         functools.partial(
-            _fused_kernel,
-            ops=tuple(ops),
-            with_presence=with_presence,
-            n_groups=block_rows // _SUBLANES,
+            _fused_kernel, ops=acc_ops, n_vals=n_aggs, n_groups=block_rows // _SUBLANES
         ),
-        grid=(kp // KEY_TILE, n_rows // block_rows),
+        grid=(n_tiles, n_rows // block_rows),
         in_specs=[row_block] * (1 + n_aggs),
-        out_specs=tuple(acc_block for _ in acc_dts),
-        out_shape=tuple(jax.ShapeDtypeStruct((kp, _LANES), dt) for dt in acc_dts),
+        out_specs=tuple(out_block for _ in acc_dts),
+        out_shape=tuple(jax.ShapeDtypeStruct((out_rows, _LANES), dt) for dt in acc_dts),
+        scratch_shapes=[pltpu.VMEM((KEY_TILE, _LANES), dt) for dt in acc_dts],
         interpret=interpret,
     )(keys_2d, *vals_2d)
-    accs = tuple(
-        _lane_reduce(op, o)[:num_keys].astype(v.dtype)
-        for op, o, v in zip(ops, outs, values)
-    )
-    pres = outs[n_aggs].sum(axis=1)[:num_keys] if with_presence else None
+    accs = tuple(o.reshape(-1)[:num_keys].astype(v.dtype) for o, v in zip(outs, values))
+    pres = outs[n_aggs].reshape(-1)[:num_keys] if with_presence else None
     return accs, pres
 
 

@@ -19,7 +19,7 @@ from .ref import fused_segreduce_ref, segreduce_ref
 
 # rows one grid step of the engine's kernel launches streams (512 lane rows
 # of 128, 256 KiB per 4-byte column): on a v5e a 3000-key COUNT over 2^25
-# rows ran faster at 2^16 than at 2^14
+# rows ran faster at 2^16 than at 2^14 (benchmarks/chip_segreduce.py)
 ENGINE_TILE = 1 << 16
 
 _OFF = ("0", "off", "never", "jnp")

@@ -7,15 +7,17 @@
 #       kernel  — Pallas segreduce (VMEM accumulator): Mosaic-compiled on
 #                 TPU; off TPU the pure-jnp fused fallback, or interpret mode
 #                 (orders of magnitude slower) when REPRO_PALLAS=1 forces it
-#                 — the per-element rate follows the mode that will run,
+#                 — the rate follows the mode that will run, and the Pallas
+#                 kernel's work is rows x key tiles (rows x keys / 64),
 #   * parallel execution of foralls: none / vmap / shard_map,
 #   * partition-field choice for indirect partitioning (skew-aware).
 #
 # Units are abstract "element-ops" (1.0 ≈ one streaming element visit).
 # The default coefficients were fitted against bench_fig2-style
-# microbenchmarks on the CPU backend (``c_kernel``, the compiled TPU rate,
-# has not been fitted on a chip yet); ``calibrate()`` re-measures them on
-# the current machine (used by benchmarks/bench_planner.py).
+# microbenchmarks on the CPU backend, except the compiled kernel's
+# (``c_kernel``, ``c_kernel_tile_agg``), fitted on a v5e against XLA's
+# segment_sum at the ``c_dense`` anchor; ``calibrate()`` re-measures the
+# CPU ones on the current machine (used by benchmarks/bench_planner.py).
 from __future__ import annotations
 
 import math
@@ -26,6 +28,7 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from repro.backends import FUSABLE_AGG_OPS, JoinSpec, ProgramSpec, fused_agg_groups
+from repro.kernels.segreduce.kernel import KEY_TILE
 from repro.kernels.segreduce.ops import pallas_mode
 
 from .cardinality import CardinalityEstimator
@@ -39,13 +42,20 @@ class CostCoefficients:
     c_dense: float = 2.5         # scatter-add per element
     c_onehot: float = 0.08       # per cell of the rows×keys one-hot matmul
     c_sort: float = 1.2          # per element per log2(rows) of argsort
-    c_kernel: float = 2.0        # per element inside the compiled kernel (TPU)
+    # The Pallas kernel streams every row once per KEY_TILE-key tile, so its
+    # rates are per element per key tile; the jnp fallback is one segment
+    # op whatever the key space.  c_kernel and c_kernel_tile_agg are fitted
+    # to a v5e (benchmarks/chip_segreduce.py): 2^25 rows took 2.06 ms per
+    # key tile for a COUNT, 3.98 ms for 3 aggregates plus presence.
+    c_kernel: float = 0.019      # per element per key tile, compiled kernel (TPU)
     c_kernel_interpret: float = 400.0  # ... in interpret mode (REPRO_PALLAS=1 off TPU)
-    c_kernel_fallback: float = 2.2     # ... in the pure-jnp fused fallback (off TPU)
+    c_kernel_fallback: float = 2.2     # per element, pure-jnp fused fallback (off TPU)
     c_kernel_fixed: float = 2e4  # kernel launch / trace overhead
-    c_kernel_agg: float = 0.7    # per element per EXTRA fused aggregate —
-    #                              another accumulator update inside the one
-    #                              pass, not another pass over the data
+    c_kernel_agg: float = 0.7    # per element per EXTRA fused aggregate in the
+    #                              fallback — another accumulator update inside
+    #                              the one pass, not another pass over the data
+    c_kernel_tile_agg: float = 0.47  # the same in the Pallas kernel, as a share
+    #                                  of its per-tile rate
     c_combine: float = 1.5       # per accumulator cell when merging partials
     c_shard_fixed: float = 5e4   # shard_map trace/collective setup
     c_join_probe: float = 3.0    # searchsorted probe per row
@@ -80,16 +90,20 @@ class CostModel:
         self.est = CardinalityEstimator(stats, profile)
 
     # -- aggregation --------------------------------------------------------
-    def _kernel_per_elem(self) -> float:
-        """Per-element cost of the segreduce kernel path under the mode the
-        runtime will actually execute (kernels/segreduce/ops.pallas_mode):
-        Mosaic-compiled on TPU, the pure-jnp fused fallback elsewhere, or
-        interpret mode when REPRO_PALLAS forces the kernel off-TPU."""
+    def _kernel_scan(self, rows: float, num_keys: float, n_aggs: int) -> float:
+        """Streaming work of one segreduce launch evaluating ``n_aggs``
+        aggregates, under the mode the runtime will actually execute
+        (kernels/segreduce/ops.pallas_mode): the Pallas kernel — compiled
+        on TPU, interpreted when REPRO_PALLAS forces it off-TPU — streams
+        every row once per key tile, the pure-jnp fused fallback once."""
         c = self.coeffs
+        extra = max(0, n_aggs - 1)
         mode = pallas_mode()
-        if mode == "compiled":
-            return c.c_kernel
-        return c.c_kernel_interpret if mode == "interpret" else c.c_kernel_fallback
+        if mode == "off":
+            return rows * (c.c_kernel_fallback + extra * c.c_kernel_agg)
+        rate = c.c_kernel if mode == "compiled" else c.c_kernel_interpret
+        tiles = math.ceil(max(1.0, num_keys) / KEY_TILE)
+        return rows * tiles * rate * (1.0 + extra * c.c_kernel_tile_agg)
 
     def agg_cost(self, rows: float, num_keys: float, method: str, op: str) -> float:
         c = self.coeffs
@@ -106,20 +120,19 @@ class CostModel:
         if method == "sort":
             return rows * c.c_sort * max(1.0, math.log2(max(2.0, rows))) + rows * c.c_dense
         if method == "kernel":
-            return c.c_kernel_fixed + rows * self._kernel_per_elem() + num_keys * c.c_output
+            return c.c_kernel_fixed + self._kernel_scan(rows, num_keys, 1) + num_keys * c.c_output
         raise ValueError(f"bad agg method {method}")
 
     def fused_agg_cost(self, rows: float, num_keys: float, n_aggs: int) -> float:
         """One fused kernel launch evaluating ``n_aggs`` accumulators plus
-        presence in a SINGLE data pass: one launch fee and one streaming
-        scan are amortized over the whole group — each extra aggregate
-        adds only an in-pass accumulator update (c_kernel_agg), not
-        another pass — versus n_aggs full launches+scans unfused."""
+        presence in a SINGLE launch: one launch fee and one streaming scan
+        (per key tile) are amortized over the whole group — each extra
+        aggregate adds only an in-pass accumulator update, not another
+        pass — versus n_aggs full launches+scans unfused."""
         c = self.coeffs
         return (
             c.c_kernel_fixed
-            + rows * self._kernel_per_elem()
-            + rows * max(0, n_aggs - 1) * c.c_kernel_agg
+            + self._kernel_scan(rows, num_keys, n_aggs)
             + n_aggs * num_keys * c.c_output
         )
 

@@ -116,6 +116,27 @@ def test_fused_blocks_groups_and_key_tiles(rng, dtype, tile):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5, atol=1e-4)
 
 
+@pytest.mark.parametrize("num_keys", [64, 1025, 2500])
+def test_fused_output_spans_key_rows_and_blocks(rng, num_keys):
+    """The kernel folds each key tile's lane partials into lanes of a dense
+    (keys / 128, 128) output, 16 key tiles per (8, 128) block: keys in
+    both halves of a row, in several rows and blocks, and a ragged last
+    tile all land where the jnp fallback puts them."""
+    n = 3000
+    keys = rng.integers(0, num_keys, n).astype(np.int32)
+    keys[:3] = [0, num_keys - 1, num_keys // 2]
+    vals = rng.integers(-1000, 1000, n).astype(np.int32)
+    ops = ("sum", "max", "min")
+    got, got_pres = fused_segreduce_pallas(
+        jnp.asarray(keys), (jnp.asarray(vals),) * 3, ops, num_keys, tile=1024, interpret=True,
+    )
+    want, want_pres = fused_segreduce_ref(jnp.asarray(keys), (jnp.asarray(vals),) * 3, ops, num_keys)
+    np.testing.assert_array_equal(np.asarray(got_pres), np.asarray(want_pres))
+    for g, w in zip(got, want):
+        assert g.shape == (num_keys,)
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 @pytest.mark.parametrize(
     "backend,env,want",
     [

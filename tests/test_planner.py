@@ -219,6 +219,26 @@ def test_chosen_plan_not_slower_than_worst(rng):
     assert t_chosen <= t_worst * 1.2
 
 
+@pytest.mark.parametrize("key_space,want_kernel", [(3000, True), (1 << 20, False)])
+def test_compiled_kernel_priced_per_key_tile(monkeypatch, rng, key_space, want_kernel):
+    """The compiled segreduce kernel streams the rows once per 64-key tile:
+    priced as it runs on a TPU, it wins a 3000-key COUNT GROUP BY and loses
+    a 2^20-key one, whatever the row count."""
+    from repro.planner import cost
+
+    monkeypatch.setattr(cost, "pallas_mode", lambda: "compiled")
+    k = rng.integers(0, key_space, 200_000).astype(np.int32)
+    k[0] = key_space - 1  # the key space is max + 1
+    d = Database().add(Multiset.from_columns("t", k=k))
+    p = sql_to_forelem("SELECT k, COUNT(k) FROM t GROUP BY k", {"t": ["k"]})
+    decision = plan_query(p, collect_stats(d))
+    assert (decision.chosen.agg_method == "kernel") == want_kernel
+    model = cost.CostModel(collect_stats(d))
+    one_tile = model.agg_cost(1e6, 64, "kernel", "+")
+    two_tiles = model.agg_cost(1e6, 65, "kernel", "+")
+    assert two_tiles - one_tile > 0.9 * (one_tile - model.coeffs.c_kernel_fixed)
+
+
 def test_planner_matches_fixed_defaults_results(db):
     d, k, v = db
     p = sql_to_forelem("SELECT k, COUNT(k), SUM(v) FROM t GROUP BY k", {"t": ["k", "v"]})
