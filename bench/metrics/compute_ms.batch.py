@@ -1,0 +1,7 @@
+"""Time per query (ms) in ``jax.compute``: the jitted program, up to its
+``block_until_ready`` (which a traced session adds)."""
+from bench.layer_read import span_ms_per_query
+
+
+def read(ctx):
+    return span_ms_per_query(ctx, ("jax.compute",))

@@ -1,0 +1,6 @@
+"""Share of the traced window (%) in which no operation ran on the device."""
+from bench.layer_read import idle_percent
+
+
+def read(ctx):
+    return idle_percent(ctx)
