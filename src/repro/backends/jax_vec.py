@@ -47,6 +47,30 @@ from .interface import register_backend
 _KERNEL_OPS = {"+": "sum", "max": "max", "min": "min"}
 
 
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` renamed, so ``jax.jit`` calls its module ``jit_<name>`` and a
+    device trace can tell the engine's programs apart."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+def program_name(spec) -> str:
+    """The monolithic program's name, from the plan's operators: ``q_``
+    then ``join``, ``groupby``, ``scalar`` and ``project``, those it has,
+    in that order (``q_groupby``, ``q_join_groupby``, ``q_scalar``)."""
+    parts = [
+        op
+        for op, has in (
+            ("join", spec.joins),
+            ("groupby", spec.aggs or spec.distinct_reads or any(j.aggs for j in spec.joins)),
+            ("scalar", spec.scalar_reduces),
+            ("project", spec.filter_projects),
+        )
+        if has
+    ]
+    return "q_" + "_".join(parts or ["empty"])
+
+
 @dataclass
 class CodegenChoices:
     """The Fig. 1 decision: how index sets are materialized and how foralls
@@ -325,7 +349,7 @@ class JaxLowering:
             ones = jnp.where(valid, ones, 0)
             return acc, self._aggregate(keys, ones, nk, "+")
 
-        return fn
+        return _named(fn, "chunk_agg")
 
     def chunk_fused_agg_fn(self, aggs, with_presence: bool = True) -> Callable:
         """(padded chunk cols, n_valid, env, arrays) -> (tuple of partial
@@ -351,7 +375,7 @@ class JaxLowering:
                 keys, values, ops, nk, mask=mask, with_presence=with_presence
             )
 
-        return fn
+        return _named(fn, "chunk_fused_agg")
 
     def chunk_reduce_fn(self, sr) -> Callable:
         """(padded chunk cols, n_valid, env, arrays) -> partial scalar sum."""
@@ -375,7 +399,7 @@ class JaxLowering:
             vals = jnp.broadcast_to(expr, (m,))
             return jnp.sum(jnp.where(mask, vals, 0))
 
-        return fn
+        return _named(fn, "chunk_reduce")
 
     def chunk_project_fn(self, fp) -> Callable:
         """(padded chunk cols, n_valid, env) -> (item columns, present mask)."""
@@ -392,7 +416,7 @@ class JaxLowering:
             )
             return items, mask
 
-        return fn
+        return _named(fn, "chunk_project")
 
     def chunk_join_fn(self, j: JoinSpec, mult: int, with_presence: bool = True) -> Callable:
         """(padded probe cols, n_valid_probe, sorted+padded build cols,
@@ -432,7 +456,7 @@ class JaxLowering:
             items = tuple(self._join_gather(el, j, jr, cols) for el in j.items)
             return items, jr.present, jr.probe_idx
 
-        return fn
+        return _named(fn, "chunk_join")
 
     # -- build the callable -------------------------------------------------------
     def build(self) -> Callable[[Dict[str, Dict[str, jnp.ndarray]]], Dict[str, Any]]:
@@ -538,7 +562,7 @@ class JaxLowering:
 
             return out
 
-        return run
+        return _named(run, program_name(spec))
 
     # distinct-read item: FieldRef(table,i,field) -> key ids;
     # ArrayRead(arr, FieldRef(...field)) -> arrays[arr][key_ids]
@@ -761,6 +785,7 @@ class Plan:
             cols = self.input_columns()
             if params:
                 cols["__params__"] = {k: jnp.asarray(v) for k, v in params.items()}
+            jax.block_until_ready(cols)  # the copy ends here, not in jax.compute
         with tracer.span("jax.compute"):
             raw = self.fn(cols)
             jax.block_until_ready(raw)  # traced runs attribute device time here

@@ -224,7 +224,10 @@ class ChunkDispatch:
     timing fields are filled in as the chunk executes: ``t_ms`` is the
     measured wall-clock (dispatch-to-complete under async_dispatch, where
     each worker blocks on its own chunk; dispatch-side time on the serial
-    path, which only blocks at merge barriers)."""
+    path, which only blocks at merge barriers).  It splits into
+    ``host_ms``, until the chunk's work returns (gather, pad, upload
+    enqueue and launch), and ``ready_ms``, the wait in
+    ``jax.block_until_ready`` (0 on the serial path)."""
 
     op: str
     partition: int
@@ -233,6 +236,8 @@ class ChunkDispatch:
     bucket: int = 0          # padded row count the kernel ran at (0 = eager)
     build_bucket: int = 0    # padded build-side rows (join kernels only)
     t_ms: float = 0.0
+    host_ms: float = 0.0
+    ready_ms: float = 0.0
     compiled: bool = False   # this dispatch triggered a fresh XLA compile
     queue_ms: float = 0.0    # dispatch-start → execution-start wait
     n_aggs: int = 1          # accumulators this dispatch produced
@@ -257,6 +262,8 @@ class ChunkDispatch:
             "bucket": self.bucket,
             "build_bucket": self.build_bucket,
             "t_ms": self.t_ms,
+            "host_ms": self.host_ms,
+            "ready_ms": self.ready_ms,
             "compiled": self.compiled,
             "queue_ms": self.queue_ms,
             "n_aggs": self.n_aggs,
@@ -744,7 +751,8 @@ class PartitionedPlan:
                                     f"{d.attempt + 1} attempts"
                                 ) from e
                             raise
-                        d.t_ms = (time.perf_counter() - t0) * 1e3
+                        d.t_ms = d.host_ms = (time.perf_counter() - t0) * 1e3
+                        d.ready_ms = 0.0
                         if traced:
                             tr.end(s, **d.trace_attrs())
                         break
@@ -875,6 +883,7 @@ class PartitionedPlan:
                     if fault is not None and fault.fault_hook is not None and not backup:
                         fault.fault_hook(d)
                     r = work(ch)
+                    t1 = time.perf_counter()
                     jax.block_until_ready(r)
                 except BaseException as e:
                     if traced:
@@ -900,7 +909,9 @@ class PartitionedPlan:
                             errors.append(err)
                         cv.notify_all()
                     continue
-                t_ms = (time.perf_counter() - t0) * 1e3
+                host_ms = (t1 - t0) * 1e3
+                ready_ms = (time.perf_counter() - t1) * 1e3
+                t_ms = host_ms + ready_ms
                 with cv:
                     if done[i]:
                         # lost the first-finisher race against a backup (or
@@ -915,7 +926,7 @@ class PartitionedPlan:
                     state["ndone"] += 1
                     results[i] = r
                     d.worker = w
-                    d.t_ms = t_ms
+                    d.t_ms, d.host_ms, d.ready_ms = t_ms, host_ms, ready_ms
                     inflight.pop(i, None)
                     if detector is not None:
                         detector.record(t_ms)
@@ -1041,12 +1052,16 @@ class PartitionedPlan:
 
             accs: List[Any] = [None] * len(gaggs)
             pres = None
-            for part in self._dispatch(chunks, work, tr):
-                paccs = part[0] if use_fused else (part[0],)
-                for i, (a, p) in enumerate(zip(gaggs, paccs)):
-                    accs[i] = self._merge(accs[i], p, a.op)
-                if need_pres:
-                    pres = self._merge(pres, part[1], "+")
+            parts = self._dispatch(chunks, work, tr)
+            with tr.span("merge"):
+                for part in parts:
+                    paccs = part[0] if use_fused else (part[0],)
+                    for i, (a, p) in enumerate(zip(gaggs, paccs)):
+                        accs[i] = self._merge(accs[i], p, a.op)
+                    if need_pres:
+                        pres = self._merge(pres, part[1], "+")
+                if tr.enabled:
+                    jax.block_until_ready((accs, pres))
             if not need_pres:
                 pres = cached_pres
             if accs[0] is None:  # empty table: identity accumulators
@@ -1175,11 +1190,14 @@ class PartitionedPlan:
             if j.aggs:
                 jaccs: Dict[str, Any] = {}
                 jpres: Dict[Tuple, Any] = {}
-                for part in parts:
-                    for ja, pk, (a_, p_) in zip(j.aggs, jpkeys, part):
-                        jaccs[ja.array] = self._merge(jaccs.get(ja.array), a_, ja.op)
-                        if need_pres:
-                            jpres[pk] = self._merge(jpres.get(pk), p_, "+")
+                with tr.span("merge"):
+                    for part in parts:
+                        for ja, pk, (a_, p_) in zip(j.aggs, jpkeys, part):
+                            jaccs[ja.array] = self._merge(jaccs.get(ja.array), a_, ja.op)
+                            if need_pres:
+                                jpres[pk] = self._merge(jpres.get(pk), p_, "+")
+                    if tr.enabled:
+                        jax.block_until_ready((jaccs, jpres))
                 if not need_pres:
                     jpres = {pk: self._presence_cache[pk] for pk in jpkeys}
                 elif j_cacheable and parts:
@@ -1246,24 +1264,35 @@ class PartitionedPlan:
                     return jnp.sum(vals)
 
             total = None
-            for part in self._dispatch(chunks, work, tr):
-                total = self._merge(total, part, "+")
+            parts = self._dispatch(chunks, work, tr)
+            with tr.span("merge"):
+                for part in parts:
+                    total = self._merge(total, part, "+")
+                if tr.enabled:
+                    jax.block_until_ready(total)
             out[sr.var] = total if total is not None else jnp.asarray(0)
 
         # --- distinct reads: one read-out over the MERGED accumulators ------
-        for dr in spec.distinct_reads:
-            nk = low.num_keys[(dr.table, dr.field)]
-            pres = presence.get((dr.table, dr.field))
-            if pres is None:
-                keys = cols[dr.table][dr.field]
-                pres = jnp.zeros((nk,), jnp.int32).at[keys].add(1)
-            key_ids = jnp.arange(nk, dtype=jnp.int32)
-            items = tuple(low._vec_distinct(el, dr, key_ids, arrays, cols) for el in dr.items)
-            present = pres > 0
-            if dr.filter_pred is not None:
-                guard = low._vec_distinct(dr.filter_pred, dr, key_ids, arrays, cols)
-                present = present & guard.astype(bool)
-            out[dr.result] = _densify({"columns": items, "present": present})
+        # (densified with the other results, below)
+        if spec.distinct_reads:
+            with tr.span("merge"):
+                for dr in spec.distinct_reads:
+                    nk = low.num_keys[(dr.table, dr.field)]
+                    pres = presence.get((dr.table, dr.field))
+                    if pres is None:
+                        keys = cols[dr.table][dr.field]
+                        pres = jnp.zeros((nk,), jnp.int32).at[keys].add(1)
+                    key_ids = jnp.arange(nk, dtype=jnp.int32)
+                    items = tuple(
+                        low._vec_distinct(el, dr, key_ids, arrays, cols) for el in dr.items
+                    )
+                    present = pres > 0
+                    if dr.filter_pred is not None:
+                        guard = low._vec_distinct(dr.filter_pred, dr, key_ids, arrays, cols)
+                        present = present & guard.astype(bool)
+                    out[dr.result] = {"columns": items, "present": present}
+                if tr.enabled:
+                    jax.block_until_ready([out[dr.result] for dr in spec.distinct_reads])
 
         # --- filter/project: streaming chunks, concatenated ------------------
         for fi, fp in enumerate(spec.filter_projects):
@@ -1299,8 +1328,9 @@ class PartitionedPlan:
             # original row order, independent of the partitioning
             out[fp.result] = [r for _, r in sorted(rows_out, key=lambda t: t[0])]
 
-        final = {k: _densify(v) for k, v in out.items() if k in self.program.results}
-        result = apply_order_limit(self.program, final)
+        with tr.span("densify"):
+            final = {k: _densify(v) for k, v in out.items() if k in self.program.results}
+            result = apply_order_limit(self.program, final)
         self.last_run_ms = (time.perf_counter() - t_run0) * 1e3
         return result
 
@@ -1332,6 +1362,8 @@ class PartitionedPlan:
                 bucket=int(r.get("bucket", 0)),
                 build_bucket=int(r.get("build_bucket", 0)),
                 t_ms=float(r.get("t_ms", 0.0)),
+                host_ms=float(r.get("host_ms", 0.0)),
+                ready_ms=float(r.get("ready_ms", 0.0)),
                 compiled=bool(r.get("compiled", False)),
                 queue_ms=float(r.get("queue_ms", 0.0)),
                 n_aggs=int(r.get("n_aggs", 1)),

@@ -294,6 +294,7 @@ class SharedChunkPool:
             if fault is not None and fault.fault_hook is not None and not backup:
                 fault.fault_hook(d)
             r = op.work(ch)
+            t1 = time.perf_counter()
             jax.block_until_ready(r)
         except BaseException as e:
             if op.traced:
@@ -327,7 +328,9 @@ class SharedChunkPool:
                     op.errors.append(err)
                 self._cv.notify_all()
             return
-        t_ms = (time.perf_counter() - t0) * 1e3
+        host_ms = (t1 - t0) * 1e3
+        ready_ms = (time.perf_counter() - t1) * 1e3
+        t_ms = host_ms + ready_ms
         with self._cv:
             if op.done[i]:
                 # lost the first-finisher race (deterministic results make
@@ -343,7 +346,7 @@ class SharedChunkPool:
             op.results[i] = r
             op.inflight.pop(i, None)
             d.worker = wid
-            d.t_ms = t_ms
+            d.t_ms, d.host_ms, d.ready_ms = t_ms, host_ms, ready_ms
             if op.detector is not None:
                 op.detector.record(t_ms)
             self._cv.notify_all()

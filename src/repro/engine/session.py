@@ -686,6 +686,8 @@ class Session:
             m.inc("chunks.dispatched", len(log))
             m.inc("rows.scanned", sum(d.rows for d in log))
             m.inc("worker.busy_ms", sum(d.t_ms for d in log))
+            m.inc("worker.host_ms", sum(d.host_ms for d in log))
+            m.inc("worker.ready_ms", sum(d.ready_ms for d in log))
             m.inc("queue.wait_ms", sum(d.queue_ms for d in log))
         rows = qr.rows
         if rows is not None:

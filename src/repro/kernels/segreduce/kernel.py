@@ -220,6 +220,7 @@ def fused_segreduce_pallas(
         out_shape=tuple(jax.ShapeDtypeStruct((out_rows, _LANES), dt) for dt in acc_dts),
         scratch_shapes=[pltpu.VMEM((KEY_TILE, _LANES), dt) for dt in acc_dts],
         interpret=interpret,
+        name="segreduce",
     )(keys_2d, *vals_2d)
     accs = tuple(o.reshape(-1)[:num_keys].astype(v.dtype) for o, v in zip(outs, values))
     pres = outs[n_aggs].reshape(-1)[:num_keys] if with_presence else None

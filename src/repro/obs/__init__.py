@@ -1,6 +1,7 @@
 # repro.obs — end-to-end observability for the query engine: per-stage
 # spans (``Tracer``/``QueryTrace``), an engine-wide ``MetricsRegistry``,
-# and Perfetto/Chrome-trace + JSON-lines export.  Zero dependencies.
+# and Perfetto/Chrome-trace + JSON-lines export.  An enabled ``Tracer``
+# also marks each span on jax's profiler clock (``repro.<span name>``).
 #
 # The engine threads a tracer through every pipeline stage:
 #
@@ -9,7 +10,10 @@
 #                    ─ plan.stats ─ plan.enumerate ─ lower
 #         ─ execute ─ dispatch:<op> ─ dispatch (one per chunk, carrying the
 #                      ChunkDispatch fields: partition, rows, worker,
-#                      bucket, compiled, queue_ms)
+#                      bucket, compiled, queue_ms, host_ms, ready_ms)
+#                   ─ merge (partitioned: partial merges, distinct reads)
+#                   ─ jax.upload ─ jax.compute (monolithic)
+#                   ─ densify
 #
 # Entry points: ``Session(trace=True)`` / ``Session.profile()`` /
 # ``Session.metrics()``; ``QueryTrace.save("x.json.gz")`` opens directly in

@@ -12,6 +12,11 @@
 #     captures the owning span's id and workers attach to it explicitly.
 #   3. Monotonic clock (``perf_counter_ns``) — spans order and nest by time;
 #     wall-clock jumps must not produce negative durations.
+#   4. On the profiler's clock too — an enabled tracer opens a profiler
+#     TraceMe named ``repro.<span name>`` with each span, so a
+#     ``jax.profiler`` trace shows the engine's stages on its ``/host:CPU``
+#     plane beside the device's ops.  Outside a profiler trace a TraceMe
+#     records nothing.
 #
 # Within one thread, spans nest implicitly (a per-thread stack), which is
 # what the serial pipeline stages use; ``parent=`` overrides.
@@ -37,6 +42,8 @@ class Span:
     t1_ns: int = 0
     tid: int = 0
     attrs: Dict[str, Any] = field(default_factory=dict)
+    # the open profiler TraceMe (closed by ``Tracer.end``)
+    traceme: Any = field(default=None, repr=False, compare=False)
 
     @property
     def dur_ms(self) -> float:
@@ -142,6 +149,9 @@ class Tracer:
     enabled = True
 
     def __init__(self, clock=time.perf_counter_ns):
+        from jax.profiler import TraceAnnotation
+
+        self._traceme = TraceAnnotation
         self._clock = clock
         self._lock = threading.Lock()
         self._spans: List[Span] = []
@@ -175,7 +185,10 @@ class Tracer:
         with self._lock:
             sid = self._next_id
             self._next_id += 1
-        span = Span(name, sid, parent, self._clock(), tid=self._tid(), attrs=dict(attrs))
+        traceme = self._traceme("repro." + name)
+        traceme.__enter__()
+        span = Span(name, sid, parent, self._clock(), tid=self._tid(), attrs=dict(attrs),
+                    traceme=traceme)
         stack.append(span)
         return span
 
@@ -185,6 +198,9 @@ class Tracer:
         if attrs:
             span.attrs.update(attrs)
         span.t1_ns = self._clock()
+        if span.traceme is not None:
+            span.traceme.__exit__(None, None, None)
+            span.traceme = None
         stack = self._stack()
         if span in stack:  # tolerate out-of-order ends across helpers
             stack.remove(span)
@@ -200,10 +216,6 @@ class Tracer:
         with self._lock:
             spans, self._spans = self._spans, []
         return sorted(spans, key=lambda s: (s.t0_ns, s.id))
-
-    def peek(self) -> List[Span]:
-        with self._lock:
-            return sorted(list(self._spans), key=lambda s: (s.t0_ns, s.id))
 
 
 class QueryTrace:
@@ -225,9 +237,6 @@ class QueryTrace:
     def roots(self) -> List[Span]:
         ids = {s.id for s in self.spans}
         return [s for s in self.spans if s.parent is None or s.parent not in ids]
-
-    def children(self, span: Span) -> List[Span]:
-        return [s for s in self.spans if s.parent == span.id]
 
     def find(self, span_id: int) -> Optional[Span]:
         for s in self.spans:

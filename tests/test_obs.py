@@ -371,3 +371,122 @@ def test_query_log_ring_buffer_and_last_query():
 def test_query_log_cap_validation():
     with pytest.raises(EngineError):
         _session(max_query_log=0)
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock; the chunk time split; merge and densify
+# ---------------------------------------------------------------------------
+
+
+class _FakeTraceMe:
+    """Stands in for jax's TraceAnnotation: records what opens and closes."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, threading.get_ident()))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, threading.get_ident()))
+        return False
+
+
+@pytest.fixture
+def fake_traceme(monkeypatch):
+    import jax
+
+    _FakeTraceMe.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeTraceMe)
+    return _FakeTraceMe.log
+
+
+def test_null_tracer_emits_no_traceme(fake_traceme):
+    s = _session()
+    assert s.tracer is NULL_TRACER
+    s.sql(Q)
+    with NULL_TRACER.span("x"):
+        NULL_TRACER.end(NULL_TRACER.start("y"))
+    assert fake_traceme == []
+
+
+def test_each_span_opens_and_closes_a_traceme(fake_traceme):
+    s = _session(trace=True)
+    s.sql(Q)
+    spans = s.take_trace().spans
+    enters = [n for kind, n, _ in fake_traceme if kind == "enter"]
+    exits = [n for kind, n, _ in fake_traceme if kind == "exit"]
+    assert sorted(enters) == sorted(exits) == sorted("repro." + sp.name for sp in spans)
+    assert {"repro.query", "repro.execute", "repro.dispatch", "repro.merge",
+            "repro.densify"} <= set(enters)
+    assert all(sp.traceme is None for sp in spans)
+
+
+def test_start_end_pair_across_threads_closes_its_traceme(fake_traceme):
+    tr = Tracer()
+    sp = tr.start("chunk")
+    t = threading.Thread(target=tr.end, args=(sp,))
+    t.start()
+    t.join()
+    (enter, exit_) = fake_traceme
+    assert enter[:2] == ("enter", "repro.chunk") and exit_[:2] == ("exit", "repro.chunk")
+    assert enter[2] != exit_[2]
+    assert [x.name for x in tr.drain()] == ["chunk"]
+
+
+@pytest.mark.parametrize("path", ["serial", "pool"])
+def test_chunk_host_and_ready_add_up_to_chunk_time(path):
+    plan = _pool_plan(_db()) if path == "pool" else get_backend("partitioned").compile(
+        sql_to_forelem(Q, SCHEMAS), _db(),
+        PartitionedChoices(n_partitions=4, schedule="fixed", async_dispatch=False))
+    plan.run()
+    log = plan.dispatch_log
+    assert len(log) > 1
+    for d in log:
+        assert d.host_ms > 0 and d.ready_ms >= 0
+        assert d.host_ms + d.ready_ms == d.t_ms
+        if path == "serial":
+            assert d.ready_ms == 0.0
+
+
+@pytest.mark.parametrize("server", [False, True])
+def test_host_and_ready_counters_add_up_to_busy(server):
+    """The session's local pool and the server's shared pool both count the
+    split beside ``worker.busy_ms``."""
+    from repro import QueryServer
+
+    if server:
+        srv = QueryServer(n_partitions=4)
+        try:
+            srv.register("t", **_cols())
+            srv.submit(Q)
+            c = srv.metrics.snapshot()["counters"]
+        finally:
+            srv.close()
+    else:
+        s = _session(async_dispatch=True)
+        s.sql(Q)
+        c = s.metrics()["counters"]
+    assert c["worker.host_ms"] > 0
+    assert c["worker.host_ms"] + c["worker.ready_ms"] == pytest.approx(c["worker.busy_ms"])
+
+
+def test_partitioned_run_has_merge_and_densify_spans():
+    s = _session(trace=True)
+    scalar = "SELECT SUM(v) FROM t WHERE k < 10"
+    s.sql(Q)
+    s.sql(scalar)
+    qt = QueryTrace(s.take_trace().spans)
+    execs = qt.by_name("execute")
+    assert len(execs) == 2
+    for name in ("merge", "densify"):
+        got = qt.by_name(name)
+        # one of each per query for these plans: the GROUP BY merges its
+        # partials and reads its keys out under one span each
+        assert len(got) >= 2, name
+        for sp in got:
+            assert qt.find(sp.parent).name == "execute"
+    assert sorted(s.sql(Q).rows) == sorted(_session().sql(Q).rows)

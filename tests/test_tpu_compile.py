@@ -7,6 +7,7 @@
 # The topology is described inside a module-scoped fixture, never while a
 # module is imported: only one process may load the TPU library at a time.
 import importlib.util
+import re
 from functools import partial
 
 import numpy as np
@@ -120,3 +121,22 @@ def test_expand_join_plan_fits_one_chip(one_chip):
     }
     mem = plan.fn.lower(cols).compile().memory_analysis()
     assert mem.temp_size_in_bytes <= 2 * mem.argument_size_in_bytes
+
+
+def test_device_programs_keep_their_names_on_the_chip(one_chip):
+    """A chunk kernel's module is ``jit_chunk_fused_agg`` and its Pallas
+    call the op ``%segreduce``, the names a v5e trace's ``XLA Modules`` and
+    ``XLA Ops`` lines carry."""
+    from repro.backends.jax_vec import _named
+    from repro.kernels.segreduce import ops as segops
+
+    def fn(keys, values, mask):
+        return segops._fused_impl(keys, (values,), mask, ("sum",), NUM_KEYS, True, "compiled")
+
+    n = 1 << 16
+    text = jax.jit(_named(fn, "chunk_fused_agg")).lower(
+        _shape(n, jnp.int32, one_chip), _shape(n, jnp.float32, one_chip),
+        _shape(n, jnp.bool_, one_chip),
+    ).compile().as_text()
+    assert text.startswith("HloModule jit_chunk_fused_agg")
+    assert re.search(r"%segreduce(\.\d+)? = .*custom_call_target=\"tpu_custom_call\"", text)
