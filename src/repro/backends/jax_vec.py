@@ -91,11 +91,13 @@ class CodegenChoices:
                               the generated-MPI-code analogue.
     join_method: 'auto'   — unique-lookup when the build key is unique on
                              the actual data, expansion otherwise,
-                'lookup'  — one searchsorted probe, one match per probe row
-                             (requires a key-unique build side),
-                'expand'  — sort + searchsorted(left/right) + gather
-                             expansion to max key multiplicity (general
-                             duplicate-key equi-join).
+                'lookup'  — one match per probe row (requires a
+                             key-unique build side),
+                'expand'  — each probe's match run, gather-expanded to
+                             max key multiplicity (general duplicate-key
+                             equi-join).
+                 Either probes a dense unique integer build key through a
+                 position table, any other by binary search (join_probe).
     """
 
     agg_method: str = "dense"
@@ -109,7 +111,16 @@ class CodegenChoices:
 class JaxLowering:
     """Compile a forelem Program into a callable over jnp column arrays."""
 
-    def __init__(self, program: Program, db: Database, choices: Optional[CodegenChoices] = None):
+    def __init__(
+        self,
+        program: Program,
+        db: Database,
+        choices: Optional[CodegenChoices] = None,
+        chunked: bool = False,
+    ):
+        """``chunked``: a chunked executor runs the joins through
+        ``_join_rows`` over each partition's sorted build side, not
+        ``build()``, so every probe binary-searches."""
         self.program = program
         self.db = db
         self.choices = choices or CodegenChoices()
@@ -119,19 +130,32 @@ class JaxLowering:
         # M output slots); M == 1 degenerates to the unique-lookup plan and
         # M == 0 marks an empty build side (all probes miss).
         self.join_multiplicity: List[int] = []
+        # Per join, the build key's (kmin, kmax) when the monolithic probe
+        # of a unique key goes through a position table over that domain,
+        # else None (binary search of the sorted build side); join_probe
+        # names the choice.  Read from the same data as M, under the same
+        # recompile rule.
+        self.join_key_range: List[Optional[Tuple[int, int]]] = []
         for j in self.spec.joins:
+            key_range = None
             if j.build_table in db and len(db[j.build_table]):
                 bk = np.asarray(db[j.build_table].field(j.build_key))
-                _, counts = np.unique(bk, return_counts=True)
+                uniq, counts = np.unique(bk, return_counts=True)
                 mult = int(counts.max()) if len(counts) else 0
+                if mult == 1 and not chunked:
+                    key_range = self._dense_key_range(j, uniq)
             else:
                 mult = 0 if j.build_table in db else 1
+            self.join_key_range.append(key_range)
             if self.choices.join_method == "lookup" and mult > 1:
                 raise UnsupportedProgram(
                     f"join_method='lookup' but build side {j.build_table}.{j.build_key} "
                     "has duplicate keys — use 'expand' or 'auto'"
                 )
             self.join_multiplicity.append(mult)
+        self.join_probe: List[str] = [
+            "search" if r is None else "direct" for r in self.join_key_range
+        ]
         # key-space sizes for dense accumulators (dictionary-encoded columns)
         self.num_keys: Dict[Tuple[str, str], int] = {}
         for agg in self.spec.aggs:
@@ -185,6 +209,23 @@ class JaxLowering:
         if not np.issubdtype(vals.dtype, np.integer):
             raise UnsupportedProgram(f"non-integer key column {table}.{fld}")
         return int(vals.max()) + 1 if len(vals) else 1
+
+    def _dense_key_range(self, j: JoinSpec, uniq: np.ndarray) -> Optional[Tuple[int, int]]:
+        """``(kmin, kmax)`` of a unique build key a position table can
+        probe: both join keys integers, the domain within int32 and at most
+        4x the build rows (the table at most 4x the key column's bytes), and
+        the table no larger than the sorted keys, order and run bounds the
+        binary search would hold instead (2 x (build + probe rows) int32)."""
+        probe_dtype = np.asarray(self.db[j.probe_table].field(j.probe_fk)).dtype
+        if not (np.issubdtype(uniq.dtype, np.integer) and np.issubdtype(probe_dtype, np.integer)):
+            return None
+        kmin, kmax = int(uniq[0]), int(uniq[-1])
+        i32 = np.iinfo(np.int32)
+        n_build, n_probe = len(uniq), len(self.db[j.probe_table])
+        slots = min(4 * n_build, 2 * (n_build + n_probe), i32.max)
+        if kmin < i32.min or kmax > i32.max or kmax - kmin + 1 > slots:
+            return None
+        return kmin, kmax
 
     # -- expression → jnp ------------------------------------------------------
     def _vec(self, e: Expr, cols: Dict[str, Dict[str, jnp.ndarray]], table: str, arrays: Dict[str, jnp.ndarray]):
@@ -499,8 +540,10 @@ class JaxLowering:
             # --- joins (unique-lookup or duplicate-key expansion) -------------
             # Before distinct reads: join-aggregates fill `arrays`/`presence`
             # that the guarded distinct-read result loops consume.
-            for j, mult in zip(spec.joins, self.join_multiplicity):
-                jr = self._join_rows(j, mult, cols)
+            for j, mult, key_range in zip(
+                spec.joins, self.join_multiplicity, self.join_key_range
+            ):
+                jr = self._join_rows(j, mult, cols, key_range=key_range)
                 if j.aggs:
                     for ja in j.aggs:
                         nk = self.num_keys[(ja.key.table, ja.key.field)]
@@ -647,9 +690,12 @@ class JaxLowering:
     # bounds each probe's match run, and the output is expanded to the
     # static shape (probe_rows × M) where M is the max key multiplicity
     # measured at compile time ('expand'); absent slots are masked out.
+    # A dense unique integer build key (``key_range``) needs no search: a
+    # table over its domain gives each probe its row.
 
     def _join_rows(
-        self, j: JoinSpec, mult: int, cols, build_sorted=None, n_valid_build=None
+        self, j: JoinSpec, mult: int, cols, build_sorted=None, n_valid_build=None,
+        key_range: Optional[Tuple[int, int]] = None,
     ) -> "_JoinRows":
         """``build_sorted`` is an optional precomputed ``(order, sorted_keys)``
         of the build side in ``cols`` — chunked executors that probe the same
@@ -659,7 +705,10 @@ class JaxLowering:
         ``n_valid_build`` sorted rows are real (the rest carry a maximal key
         sentinel), so match runs are clipped to it.  Padding sorts to the
         end, which keeps every real match run inside the valid prefix even
-        when real keys equal the sentinel value."""
+        when real keys equal the sentinel value.
+
+        ``key_range`` is the build key's ``(kmin, kmax)`` from
+        ``join_key_range``: the probe goes through a position table."""
         bk = cols[j.build_table][j.build_key]
         pk = cols[j.probe_table][j.probe_fk]
         n_probe = pk.shape[0]
@@ -670,6 +719,20 @@ class JaxLowering:
             return _JoinRows(
                 None, jnp.zeros((n_probe,), jnp.int32), jnp.zeros((n_probe,), bool), True
             )
+        if key_range is not None:
+            kmin, kmax = key_range
+            # a key outside the domain misses; it never clamps onto a slot
+            pk = pk.astype(jnp.int32)
+            inr = (pk >= kmin) & (pk <= kmax)
+            # M == 1 is exact for the data compiled for, so the unique key
+            # gives the 1:1 layout a width-1 expansion computes
+            rows = jnp.full((kmax - kmin + 1,), -1, jnp.int32).at[bk - kmin].set(
+                jnp.arange(bk.shape[0], dtype=jnp.int32), unique_indices=True
+            )[jnp.where(inr, pk - kmin, 0)]
+            present = inr & (rows >= 0)
+            if pmask is not None:
+                present = present & pmask
+            return _JoinRows(None, jnp.maximum(rows, 0), present, False)
         if build_sorted is not None:
             order, sk = build_sorted
         else:

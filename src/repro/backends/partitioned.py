@@ -360,7 +360,9 @@ class PartitionedPlan:
         # per-chunk kernels come from the existing vectorized lowering; the
         # forall strategy inside a chunk is always 'none' (the partitioned
         # runner IS the parallel execution strategy)
-        self.lowering = JaxLowering(program, db, replace(choices.base, parallel="none"))
+        self.lowering = JaxLowering(
+            program, db, replace(choices.base, parallel="none"), chunked=True
+        )
         self.spec = self.lowering.spec
         # numpy view of every needed column (sliced per chunk at run time)
         self._cols_np: Dict[str, Dict[str, np.ndarray]] = {}

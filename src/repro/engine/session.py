@@ -681,6 +681,8 @@ class Session:
             m.inc("jit.compiles", max(0, jit_after[0] - jit_before[0]))
             m.inc("jit.hits", max(0, jit_after[1] - jit_before[1]))
             m.inc("jit.overflows", max(0, jit_after[2] - jit_before[2]))
+        for method in getattr(getattr(res.plan, "lowering", None), "join_probe", ()):
+            m.inc("join.probe", method=method)
         log = getattr(res.plan, "dispatch_log", None)
         if log:
             m.inc("chunks.dispatched", len(log))

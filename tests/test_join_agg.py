@@ -1,6 +1,7 @@
 # Differential reference-vs-JAX tests for the general equi-join engine
 # (duplicate build keys via sort + searchsorted(left/right) + gather
-# expansion), GROUP BY over a two-table join, and the filtered MIN/MAX
+# expansion; a dense integer build key through a position table), GROUP BY
+# over a two-table join, and the filtered MIN/MAX
 # aggregation paths across every agg_method.  The ReferenceInterpreter is
 # the oracle throughout.
 import numpy as np
@@ -185,6 +186,117 @@ def test_lookup_forced_on_duplicates_refuses(rng):
     p = sql_to_forelem("SELECT a.f, b.g FROM A a, B b WHERE a.b_id = b.id", SCHEMAS)
     with pytest.raises(UnsupportedProgram):
         Plan(p, db, CodegenChoices(join_method="lookup"))
+
+
+# ---------------------------------------------------------------------------
+# direct-address probe of a dense unique integer build key
+# ---------------------------------------------------------------------------
+
+# case -> (build keys, probe keys, extra probe filter, expected probe)
+PROBE_CASES = {
+    "dense_unique": (lambda r: r.permutation(40), lambda r: r.integers(0, 40, 300), "", "direct"),
+    "dense_duplicates": (lambda r: r.integers(0, 12, 40), lambda r: r.integers(0, 12, 300), "",
+                         "search"),
+    "offset_domain": (lambda r: 1000 + r.permutation(40), lambda r: r.integers(1000, 1040, 300),
+                      "", "direct"),
+    "negative_domain": (lambda r: r.permutation(40) - 25, lambda r: r.integers(-25, 15, 300), "",
+                        "direct"),
+    "probes_outside_domain": (lambda r: 10 + r.permutation(20), lambda r: r.integers(-5, 45, 300),
+                              "", "direct"),
+    "filter_empties_probe": (lambda r: r.permutation(40), lambda r: r.integers(0, 40, 300),
+                             " AND a.w > 1000", "direct"),
+    "sparse_domain": (lambda r: 8 * r.permutation(40), lambda r: 8 * r.integers(0, 40, 300), "",
+                      "search"),
+    # 118 slots fit 4x the 40 build rows but not the 2 x (40 + 10) the search holds
+    "table_above_search_bytes": (lambda r: 3 * r.permutation(40),
+                                 lambda r: 3 * r.integers(0, 40, 10), "", "search"),
+}
+PROBE_SQL = (
+    "SELECT a.f, b.g FROM A a, B b WHERE a.b_id = b.id{}",
+    "SELECT b.g, COUNT(b.g), SUM(a.w), MIN(b.v) FROM A a, B b WHERE a.b_id = b.id{} GROUP BY b.g",
+)
+
+
+@pytest.mark.parametrize("join_method", ["auto", "expand"])
+@pytest.mark.parametrize("sql", PROBE_SQL, ids=["join", "groupby"])
+@pytest.mark.parametrize("case", sorted(PROBE_CASES))
+def test_direct_probe_matches_reference(case, sql, join_method):
+    build, probe, where, expected = PROBE_CASES[case]
+    r = np.random.default_rng(14)
+    b_id, a_id = build(r).astype(np.int32), probe(r).astype(np.int32)
+    A = Multiset.from_columns("A", b_id=a_id, f=r.integers(0, 6, len(a_id)).astype(np.int32),
+                              w=r.integers(-50, 50, len(a_id)).astype(np.int32))
+    B = Multiset.from_columns("B", id=b_id, g=r.integers(0, 5, len(b_id)).astype(np.int32),
+                              v=r.integers(-30, 30, len(b_id)).astype(np.int32))
+    db = Database().add(A).add(B)
+    p = sql_to_forelem(sql.format(where), SCHEMAS)
+    plan = Plan(p, db, CodegenChoices(join_method=join_method))
+    assert plan.lowering.join_probe == [expected]
+    ref = ref_rows(p, db)
+    assert sorted(plan.run()["R"]) == ref
+    if where:
+        assert ref == []
+
+
+@pytest.fixture
+def q3a_tables(monkeypatch):
+    """BDB Q3A's tables at the benchmark's tiny CPU size, and its SQL."""
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    monkeypatch.syspath_prepend(str(root))
+    from bench import query, spec
+    from bench.tests._tiny import tiny_config
+
+    bench = spec.load_benchmark(root)
+    cfg = tiny_config(bench, "bdb.join.batch")
+    traffic = spec.traffic(spec.workload(bench, "bdb.join.batch")["traffic"])
+    tables = spec.generator(cfg).generate(cfg, 2**31 + 14)
+    return tables, query.to_sql(traffic["templates"][0]["query"])
+
+
+@pytest.mark.parametrize("spread,probe,searches", [(1, "direct", False), (8, "search", True)])
+def test_q3a_program_has_no_search_loop_on_a_dense_key(q3a_tables, spread, probe, searches):
+    # the binary search lowers to a `while` of log2(build rows) gathers of
+    # every probe row; a dense key (pageURL is a permutation) needs none,
+    # a key spread 8x over its domain still searches
+    from repro import Session
+
+    tables, sql = q3a_tables
+    s = Session(revalidate="signature")
+    s.register("rankings", **dict(tables["rankings"], pageURL=tables["rankings"]["pageURL"] * spread))
+    s.register("uservisits", **dict(tables["uservisits"],
+                                    destURL=tables["uservisits"]["destURL"] * spread))
+    plan = s.sql(sql).plan
+    assert plan.lowering.join_probe == [probe]
+    text = plan.fn.lower(plan.input_columns()).as_text()
+    assert ("stablehlo.while" in text) == searches
+
+
+def test_q3a_direct_probe_sums_bit_identical_to_search(q3a_tables, monkeypatch):
+    # the position table matches the same pairs in the same probe-row order
+    # as the width-1 expansion, so even the float sums agree to the bit
+    import jax
+
+    from repro import Session
+    from repro.backends import jax_vec
+
+    tables, sql = q3a_tables
+
+    def raw_outputs():
+        s = Session(revalidate="signature")
+        for name, cols in tables.items():
+            s.register(name, **cols)
+        plan = s.sql(sql).plan
+        return plan.lowering.join_probe, jax.tree.leaves(plan.fn(plan.input_columns()))
+
+    probe, direct = raw_outputs()
+    monkeypatch.setattr(jax_vec.JaxLowering, "_dense_key_range", lambda self, j, uniq: None)
+    probe_searched, searched = raw_outputs()
+    assert (probe, probe_searched) == (["direct"], ["search"])
+    assert len(direct) == len(searched)
+    for a, b in zip(direct, searched):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ---------------------------------------------------------------------------

@@ -273,6 +273,18 @@ def test_metrics_registry_rejects_negative_and_shares():
     assert reg.counter_total("queries") == 2   # both sessions feed one registry
 
 
+@pytest.mark.parametrize("backend,method", [("jax", "direct"), ("partitioned", "search")])
+def test_join_probe_counter_names_the_probe(backend, method):
+    # a dense build key is probed through a position table on the
+    # monolithic path; chunk joins binary-search each sorted partition
+    s = _session(backend=backend)
+    s.register("d", id=np.arange(40, dtype=np.int32), w=np.arange(40, dtype=np.int32))
+    s.sql("SELECT t.k, SUM(d.w) FROM d, t WHERE d.id = t.k GROUP BY t.k")
+    counters = s.metrics_registry.snapshot()["counters"]
+    assert counters[f"join.probe{{method={method}}}"] == 1
+    assert not any(k.startswith("join.probe") and method not in k for k in counters)
+
+
 def test_table_replacement_counts_invalidations():
     s = _session()
     s.sql(Q)
