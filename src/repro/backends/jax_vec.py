@@ -45,6 +45,9 @@ from .interface import register_backend
 
 # engine accumulate-op spelling -> segreduce kernel spelling
 _KERNEL_OPS = {"+": "sum", "max": "max", "min": "min"}
+# engine accumulate-op spelling -> the jax.lax collective that combines a
+# shard_map aggregate's per-device partials
+_COMBINE = {"+": "psum", "max": "pmax", "min": "pmin"}
 
 
 def _named(fn: Callable, name: str) -> Callable:
@@ -178,6 +181,16 @@ class JaxLowering:
         # here are surfaced by the optimizer into the trace and the
         # decision's rejections so the downgrade is never silent.
         self.method_notes: List[str] = []
+        # shard_map only: the row count of each table Plan places on the
+        # mesh as row shards, padded to a multiple of the mesh size (rows
+        # past the count add each op's identity), and the combine each run
+        # issues per accumulator: each aggregate's value, then each key's
+        # presence count.
+        self.mesh_rows: Dict[str, int] = self._mesh_rows()
+        mesh_aggs = [a for a in self.spec.aggs if a.table in self.mesh_rows]
+        self.collectives: List[str] = [_COMBINE[a.op] for a in mesh_aggs] + [
+            _COMBINE["+"] for _ in {(a.table, a.key_field) for a in mesh_aggs}
+        ]
         if self.choices.agg_method in ("onehot", "kernel"):
             supported = ("+",) if self.choices.agg_method == "onehot" else FUSABLE_AGG_OPS
             labelled = [
@@ -194,6 +207,28 @@ class JaxLowering:
                         f"agg_method={self.choices.agg_method!r} — "
                         "this aggregate falls back to 'dense'"
                     )
+
+    def _mesh_rows(self) -> Dict[str, int]:
+        """Tables the shard_map path splits over the mesh: those the program
+        reads through its aggregates alone (a join, scalar reduction,
+        projection, membership set or key-column distinct read needs every
+        row, unpadded), with their row counts."""
+        c, spec = self.choices, self.spec
+        if c.parallel != "shard_map" or spec.n_parts <= 1:
+            return {}
+        if c.mesh is None:
+            raise UnsupportedProgram("shard_map parallel requires a mesh")
+        keyed = {(a.table, a.key_field) for a in spec.aggs}
+        whole = {t for j in spec.joins for t in (j.probe_table, j.build_table)}
+        whole |= {sr.table for sr in spec.scalar_reduces}
+        whole |= {fp.table for fp in spec.filter_projects}
+        whole |= {a.member_filter[1] for a in spec.aggs if a.member_filter is not None}
+        whole |= {dr.table for dr in spec.distinct_reads if (dr.table, dr.field) not in keyed}
+        tables = {a.table for a in spec.aggs} - whole
+        bad = sorted({a.op for a in spec.aggs if a.table in tables} - set(_COMBINE))
+        if bad:
+            raise UnsupportedProgram(f"shard_map cannot combine op(s) {bad}")
+        return {t: len(self.db[t]) for t in tables}
 
     def _key_space(self, table: str, fld: str) -> int:
         col = self.db[table].columns[fld]
@@ -518,6 +553,13 @@ class JaxLowering:
             if self.fused_groups and self.choices.parallel == "none":
                 fused_at = {g[0]: g for g in self.fused_groups}
             fused_members = {i for g in fused_at.values() for i in g}
+            # a key's presence is the last one written: only its writer
+            # computes it (one presence combine per key on the mesh)
+            writer = {
+                (a.table, a.key_field): ai
+                for ai, a in enumerate(spec.aggs)
+                if ai in fused_at or ai not in fused_members
+            }
             for ai, agg in enumerate(spec.aggs):
                 nk = self.num_keys[(agg.table, agg.key_field)]
                 group = fused_at.get(ai)
@@ -533,9 +575,13 @@ class JaxLowering:
                     continue
                 if ai in fused_members:
                     continue  # evaluated with its group above
-                safe_keys, values, ones, mask = self.agg_inputs(agg, cols, arrays)
-                arrays[agg.array] = self._parallel_aggregate(safe_keys, values, nk, agg.op, mask)
-                presence[(agg.table, agg.key_field)] = self._parallel_aggregate(safe_keys, ones, nk, "+", mask)
+                safe_keys, values, ones, _ = self.agg_inputs(agg, cols, arrays)
+                n_valid = self.mesh_rows.get(agg.table)
+                arrays[agg.array] = self._parallel_aggregate(safe_keys, values, nk, agg.op, n_valid)
+                if writer[(agg.table, agg.key_field)] == ai:
+                    presence[(agg.table, agg.key_field)] = self._parallel_aggregate(
+                        safe_keys, ones, nk, "+", n_valid
+                    )
 
             # --- joins (unique-lookup or duplicate-key expansion) -------------
             # Before distinct reads: join-aggregates fill `arrays`/`presence`
@@ -627,9 +673,13 @@ class JaxLowering:
         raise UnsupportedProgram(f"distinct item {e!r}")
 
     # -- parallel aggregation (the forall execution strategies) -----------------
-    def _parallel_aggregate(self, keys, values, nk: int, op: str, mask):
-        c = self.choices
-        if c.parallel == "none" or self.spec.n_parts <= 1:
+    def _parallel_aggregate(self, keys, values, nk: int, op: str, n_valid: Optional[int]):
+        """``n_valid``: the rows of a table placed on the mesh (``mesh_rows``),
+        whose columns arrive as row shards padded past it; None for a table
+        held whole."""
+        if n_valid is not None:
+            return self._mesh_aggregate(keys, values, nk, op, n_valid)
+        if self.choices.parallel != "vmap" or self.spec.n_parts <= 1:
             return self._aggregate(keys, values, nk, op)
         n = self.spec.n_parts
         pad = (-len(keys)) % n
@@ -639,48 +689,38 @@ class JaxLowering:
             # and corrupts its max/min exactly like an unmasked filtered row
             fill = jnp.full((pad,), _op_identity(op, values.dtype), values.dtype)
             values = jnp.concatenate([values, fill])
-        keys = keys.reshape(n, -1)
-        values = values.reshape(n, -1)
-        if c.parallel == "vmap":
-            partials = jax.vmap(lambda k, v: self._aggregate(k, v, nk, op))(keys, values)
-            if op == "+":
-                return partials.sum(0)
-            return partials.max(0) if op == "max" else partials.min(0)
-        if c.parallel == "shard_map":
-            from jax import shard_map
-            from jax.sharding import PartitionSpec as P
+        partials = jax.vmap(lambda k, v: self._aggregate(k, v, nk, op))(
+            keys.reshape(n, -1), values.reshape(n, -1)
+        )
+        if op == "+":
+            return partials.sum(0)
+        return partials.max(0) if op == "max" else partials.min(0)
 
-            mesh = c.mesh
-            if mesh is None:
-                raise UnsupportedProgram("shard_map parallel requires a mesh")
-            ax = c.axis_name
+    def _mesh_aggregate(self, keys, values, nk: int, op: str, n_valid: int):
+        """SPMD over the mesh axis: each device reduces the rows it holds,
+        then one collective (psum/pmax/pmin, the partitioned merge's
+        analogue) leaves the combined accumulator on every device."""
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
 
-            def local(k, v):
-                # each device may hold several of the n_parts row blocks
-                # (mesh smaller than n_parts): reduce them all locally, then
-                # combine across the axis with the op's collective —
-                # psum/pmax/pmin are the partitioned-merge analogues, so
-                # max/min no longer raise UnsupportedProgram here
-                acc = self._aggregate(k.reshape(-1), v.reshape(-1), nk, op)
-                if op == "+":
-                    acc = jax.lax.psum(acc, ax)
-                elif op == "max":
-                    acc = jax.lax.pmax(acc, ax)
-                elif op == "min":
-                    acc = jax.lax.pmin(acc, ax)
-                else:
-                    raise UnsupportedProgram(f"shard_map op {op}")
-                return acc[None]
+        if n_valid < keys.shape[0]:
+            # rows Plan padded past the table: key 0 with the op identity
+            valid = jnp.arange(keys.shape[0], dtype=jnp.int32) < n_valid
+            keys = jnp.where(valid, keys, 0)
+            values = jnp.where(valid, values, _op_identity(op, values.dtype))
+        ax = self.choices.axis_name
+        combine = getattr(jax.lax, _COMBINE[op])
 
-            # check_vma=False: a pallas_call's out shapes carry no
-            # varying-axes type, so the checked mode cannot type the
-            # segreduce kernel; the combine above is explicit anyway
-            f = shard_map(
-                local, mesh=mesh, in_specs=(P(ax), P(ax)), out_specs=P(ax), check_vma=False
-            )
-            res = f(keys, values)
-            return res[0]
-        raise ValueError(f"bad parallel {c.parallel}")
+        def local(k, v):
+            return combine(self._aggregate(k, v, nk, op), ax)
+
+        # check_vma=False: a pallas_call's out shapes carry no varying-axes
+        # type, so the checked mode cannot type the segreduce kernel; the
+        # combine above makes the result the same on every device
+        f = shard_map(
+            local, mesh=self.choices.mesh, in_specs=(P(ax), P(ax)), out_specs=P(), check_vma=False
+        )
+        return f(keys, values)
 
     # -- equi-join engine --------------------------------------------------------
     #
@@ -812,7 +852,10 @@ class _JoinRows:
 class Plan:
     """A compiled forelem program.  ``run(db)`` executes on a Database and
     densifies multiset results back to Python tuples (for comparison with the
-    reference interpreter); ``fn`` is the raw jitted callable."""
+    reference interpreter); ``fn`` is the raw jitted callable.
+
+    ``upload_bytes``: the bytes the last ``input_columns`` placed, by
+    placement (``sharded`` over the mesh, ``single`` on the default device)."""
 
     def __init__(self, program: Program, db: Database, choices: Optional[CodegenChoices] = None, jit: bool = True):
         self.program = program
@@ -820,18 +863,41 @@ class Plan:
         self.lowering = JaxLowering(program, db, choices)
         raw = self.lowering.build()
         self.fn = jax.jit(raw) if jit else raw
+        c = self.lowering.choices
+        self._rows_sharding = (
+            jax.sharding.NamedSharding(c.mesh, jax.sharding.PartitionSpec(c.axis_name))
+            if self.lowering.mesh_rows
+            else None
+        )
+        self.upload_bytes: Dict[str, int] = {}
+
+    @property
+    def n_devices(self) -> int:
+        """Devices the input columns are placed on."""
+        s = self._rows_sharding
+        return 1 if s is None else s.mesh.shape[self.lowering.choices.axis_name]
 
     def input_columns(self) -> Dict[str, Dict[str, jnp.ndarray]]:
         cols: Dict[str, Dict[str, jnp.ndarray]] = {}
+        placed = {"sharded": 0, "single": 0}
         needed = required_columns(self.program, self.lowering.spec)
         for t, fields in needed.items():
             if t not in self.db:
                 continue
             ms = self.db[t]
             cols[t] = {}
+            sharded = t in self.lowering.mesh_rows
             for f in fields:
                 if f in ms.columns:
-                    cols[t][f] = jnp.asarray(ms.field(f))
+                    host = ms.field(f)
+                    col = (
+                        _place_rows(np.asarray(host), self._rows_sharding, self.n_devices)
+                        if sharded
+                        else jnp.asarray(host)
+                    )
+                    cols[t][f] = col
+                    placed["sharded" if sharded else "single"] += col.nbytes
+        self.upload_bytes = {k: v for k, v in placed.items() if v}
         return cols
 
     def run(
@@ -844,7 +910,7 @@ class Plan:
             raw = self.fn(cols)
             out = {k: _densify(v) for k, v in raw.items() if k in self.program.results}
             return apply_order_limit(self.program, out)
-        with tracer.span("jax.upload"):
+        with tracer.span("jax.upload", devices=self.n_devices):
             cols = self.input_columns()
             if params:
                 cols["__params__"] = {k: jnp.asarray(v) for k, v in params.items()}
@@ -855,6 +921,22 @@ class Plan:
         with tracer.span("densify"):
             out = {k: _densify(v) for k, v in raw.items() if k in self.program.results}
             return apply_order_limit(self.program, out)
+
+
+def _place_rows(host: np.ndarray, sharding: Any, n_devices: int) -> jax.Array:
+    """``host`` split by rows over ``sharding``'s mesh axis, each device sent
+    only its own rows; the last shard is padded with zeros up to a multiple
+    of ``n_devices`` (the lowering masks rows past the table's count)."""
+    total = -(-len(host) // n_devices) * n_devices
+
+    def rows(index: Tuple[slice, ...]) -> np.ndarray:
+        start, stop, _ = index[0].indices(total)
+        part = host[start:stop]
+        if len(part) < stop - start:
+            part = np.concatenate([part, np.zeros(stop - start - len(part), host.dtype)])
+        return part
+
+    return jax.make_array_from_callback((total,), sharding, rows)
 
 
 class JaxBackend:

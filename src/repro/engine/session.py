@@ -681,8 +681,13 @@ class Session:
             m.inc("jit.compiles", max(0, jit_after[0] - jit_before[0]))
             m.inc("jit.hits", max(0, jit_after[1] - jit_before[1]))
             m.inc("jit.overflows", max(0, jit_after[2] - jit_before[2]))
-        for method in getattr(getattr(res.plan, "lowering", None), "join_probe", ()):
+        lowering = getattr(res.plan, "lowering", None)
+        for method in getattr(lowering, "join_probe", ()):
             m.inc("join.probe", method=method)
+        for op in getattr(lowering, "collectives", ()):
+            m.inc("mesh.collectives", op=op)
+        for placement, n in getattr(res.plan, "upload_bytes", {}).items():
+            m.inc("upload.bytes", n, placement=placement)
         log = getattr(res.plan, "dispatch_log", None)
         if log:
             m.inc("chunks.dispatched", len(log))

@@ -15,7 +15,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro import OptimizeOptions, optimize, sql_to_forelem
 from repro.data.multiset import Database, Multiset
@@ -27,22 +27,32 @@ NUM_KEYS = 3000
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
 
     # the only reason to skip: no TPU library is installed to compile with.
     # Any failure to describe the topology with it installed fails the tests.
     if importlib.util.find_spec("libtpu") is None:
         pytest.skip("libtpu is not installed")
-    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     # a compile for a described chip can be written to the persistent cache
     # but not read back; keep these compiles out of it
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        yield SingleDeviceSharding(topo.devices[0])
+        yield desc
     finally:
         jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    return Mesh(np.array(topo.devices), ("data",))
 
 
 def _shape(n, dtype, sharding):
@@ -140,3 +150,37 @@ def test_device_programs_keep_their_names_on_the_chip(one_chip):
     ).compile().as_text()
     assert text.startswith("HloModule jit_chunk_fused_agg")
     assert re.search(r"%segreduce(\.\d+)? = .*custom_call_target=\"tpu_custom_call\"", text)
+
+
+def test_mesh_groupby_fits_four_chips(four_chips, monkeypatch):
+    """The BDB aggregation over four chips: 2^27 rows arrive as one 2^25-row
+    shard a chip, each chip runs the kernel over its own rows, and one
+    all-reduce per accumulator (the sum, the presence count) combines them;
+    no row column moves between chips."""
+    from repro.kernels.segreduce import ops as segops
+
+    monkeypatch.setattr(segops, "pallas_mode", lambda: "compiled")
+    rows, n = 1 << 27, 4096
+    db = Database().add(Multiset.from_columns(
+        "uservisits",
+        ip7=(np.arange(n) % 2048).astype(np.int32),
+        adRevenue=np.random.default_rng(0).random(n).astype(np.float32),
+    ))
+    prog = sql_to_forelem("SELECT ip7, SUM(adRevenue) FROM uservisits GROUP BY ip7",
+                          {"uservisits": ["ip7", "adRevenue"]})
+    plan = optimize(prog, db, OptimizeOptions(
+        n_parts=4, agg_method="kernel", parallel_exec="shard_map", mesh=four_chips,
+        reformat=False)).plan
+    assert plan.lowering.mesh_rows == {"uservisits": n}
+    plan.lowering.mesh_rows["uservisits"] = rows  # the cell's table, before the trace
+    sharded = NamedSharding(four_chips, PartitionSpec("data"))
+    cols = {"uservisits": {"ip7": jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=sharded),
+                           "adRevenue": jax.ShapeDtypeStruct((rows,), jnp.float32, sharding=sharded)}}
+    compiled = plan.fn.lower(cols).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert len(re.findall(r" all-reduce(-start)?\(", text)) == 2
+    assert not re.search(r"(all-gather|all-to-all|collective-permute|reduce-scatter)(-start)?\(", text)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == 2 * 4 * rows // 4
+    assert mem.temp_size_in_bytes <= mem.argument_size_in_bytes
