@@ -159,6 +159,12 @@ class JaxLowering:
         self.join_probe: List[str] = [
             "search" if r is None else "direct" for r in self.join_key_range
         ]
+        # The path ('mxu' or 'vpu', kernels/segreduce.kernel_path) of each
+        # segreduce kernel launch of the whole-table program, recorded while
+        # ``build()``'s program is traced; none under the jnp fallback, and
+        # none for the chunk programs of the partitioned backend.
+        self.segreduce_path: List[str] = []
+        self._launches: Optional[List[str]] = None
         # key-space sizes for dense accumulators (dictionary-encoded columns)
         self.num_keys: Dict[Tuple[str, str], int] = {}
         for agg in self.spec.aggs:
@@ -319,8 +325,15 @@ class JaxLowering:
                 return jax.ops.segment_min(sv, sk, num_segments=num_keys, indices_are_sorted=True)
             raise UnsupportedProgram(op)
         if method == "kernel":
+            self._note_launch((_KERNEL_OPS[op],), (values,), num_keys)
             return segops.segreduce(keys, values, num_keys, op=_KERNEL_OPS[op])
         raise ValueError(f"bad agg method {method}")
+
+    def _note_launch(self, ops, values, num_keys: int) -> None:
+        if self._launches is not None:
+            path = segops.launch_path(ops, [v.dtype for v in values], num_keys)
+            if path is not None:
+                self._launches.append(path)
 
     # -- shared per-op input preparation ----------------------------------------
     #
@@ -539,6 +552,7 @@ class JaxLowering:
         spec = self.spec
 
         def run(cols: Dict[str, Dict[str, jnp.ndarray]]) -> Dict[str, Any]:
+            self._launches = []
             arrays: Dict[str, jnp.ndarray] = {}
             presence: Dict[Tuple[str, str], jnp.ndarray] = {}
             out: Dict[str, Any] = {}
@@ -566,9 +580,9 @@ class JaxLowering:
                 if group is not None:
                     gaggs = [spec.aggs[i] for i in group]
                     keys, values, mask = self.fused_agg_inputs(gaggs, cols, arrays)
-                    accs, pres = segops.fused_segreduce(
-                        keys, values, tuple(_KERNEL_OPS[a.op] for a in gaggs), nk, mask=mask
-                    )
+                    ops = tuple(_KERNEL_OPS[a.op] for a in gaggs)
+                    self._note_launch(ops, values, nk)
+                    accs, pres = segops.fused_segreduce(keys, values, ops, nk, mask=mask)
                     for a, acc in zip(gaggs, accs):
                         arrays[a.array] = acc
                     presence[(agg.table, agg.key_field)] = pres
@@ -649,6 +663,7 @@ class JaxLowering:
                     mask = jnp.ones((n,), bool)
                 out[fp.result] = {"columns": items, "present": mask}
 
+            self.segreduce_path, self._launches = self._launches, None
             return out
 
         return _named(run, program_name(spec))

@@ -684,6 +684,8 @@ class Session:
         lowering = getattr(res.plan, "lowering", None)
         for method in getattr(lowering, "join_probe", ()):
             m.inc("join.probe", method=method)
+        for path in getattr(lowering, "segreduce_path", ()):
+            m.inc("segreduce.path", path=path)
         for op in getattr(lowering, "collectives", ()):
             m.inc("mesh.collectives", op=op)
         for placement, n in getattr(res.plan, "upload_bytes", {}).items():

@@ -2,34 +2,54 @@
 # fused multi-aggregate.
 #
 # TPU adaptation of the paper's hash-table index-set materialization
-# (Fig. 1 bottom): scalar hashing is hostile to the VPU, so each key id owns
-# a row of a VMEM-resident accumulator block and every input row is compared
-# against a whole tile of key ids at once.  A hit selects the row's value,
-# a miss the op's identity, and the accumulator folds it in with the op
-# itself (add / max / min) — one masked VPU reduction for every op and dtype,
-# so integer sums (SQL COUNT is an int32 sum of ones) stay exact and never
-# need an integer matmul, which the MXU does not offer.
+# (Fig. 1 bottom): scalar hashing is hostile to the VPU, so no row looks its
+# key up.  Two paths evaluate a launch; ``kernel_path`` picks one from the
+# launch's ops, dtypes and key space, and the engine's lowering asks it too.
+#
+# VPU path (any op): each key id owns a row of a VMEM-resident accumulator
+# block and every input row is compared against a whole tile of key ids at
+# once.  A hit selects the row's value, a miss the op's identity, and the
+# accumulator folds it in with the op itself (add / max / min) — one masked
+# VPU reduction for every op and dtype.  Its work is rows x key tiles.
+#
+# MXU path (sum groups of f32, bf16 and int32 columns over more than a few
+# key tiles): a key splits as ``hi * 16 + lo``, and a grid step contracts
+# its rows on the MXU: the one-hot of ``lo`` times each value term (16 x
+# rows) against the one-hot of ``hi`` (rows x 128), so one 2,048-key tile
+# costs one matmul per 128 rows.  Both one-hots are built in VMEM per 128
+# rows and never reach HBM.  The MXU multiplies bf16, so a value enters as
+# terms that are exact in bf16: an f32 as three (v = v1 + v2 + v3), a bf16
+# as one, an int32 as its four bytes (0..255).  Every product is exact and
+# accumulates in f32, so an f32 sum differs from the VPU path's only in the
+# order of its additions (the steps' partials fold in with a compensated
+# add), and an int32 sum recombines its bytes' exact per-step counts with
+# int32 shifts and adds, wrapping as the VPU path's adds wrap.  Presence is
+# the one-hot of ``lo`` itself.  A non-finite value would turn the zeros of
+# the one-hot of ``hi`` into NaNs, so it enters as 0, and a step that holds
+# one counts its +inf/NaN and -inf/NaN rows per key in a second contraction;
+# the last step turns those counts into inf, -inf or NaN, as IEEE adds do.
 #
 # The fused kernel evaluates a whole query's aggregate group in ONE
-# pallas_call: per row it builds the key-tile hit mask once and drives every
-# aggregate's accumulator plus the group-presence histogram from it.  The
-# filter mask is folded into the keys before the call (a masked row gets key
-# -1, which matches no key id), so masked and padded rows contribute each
-# op's identity by construction.
+# pallas_call: every aggregate's accumulator plus the group-presence
+# histogram from one pass over the rows.  The filter mask is folded into
+# the keys before the call (a masked row gets key -1, which matches no key
+# id: on the MXU path its ``hi`` is -1), so masked and padded rows
+# contribute each op's identity by construction.
 #
 # Layout: every column is viewed lane-dense as (rows, 128) — one input block
 # is (block_rows, 128), so HBM and VMEM hold the data at its own size (an
-# (N, 1) column would take a whole 128-lane row per element).  Key ids run
-# along sublanes: a VMEM accumulator is (KEY_TILE, 128), one key id per row
-# and one lane partial per lane.  The grid is (key tiles, row blocks): the
-# row axis is the reduction, so each key tile's accumulators stay resident
-# while every row block streams past them (TPU grids run sequentially, so
-# the read-modify-write accumulation is race-free).  After the last row
-# block the kernel folds the lane partials and writes the tile's keys along
-# the lanes of a dense (keys / 128, 128) output, 4 bytes a key.  The work is
-# rows x key tiles: the planner prices it so (planner/cost.py).  Integer and
-# float accumulators are preserved; sub-f32 floats accumulate in f32 and are
-# cast back.
+# (N, 1) column would take a whole 128-lane row per element).  The grid is
+# (key tiles, row blocks): the row axis is the reduction, so each key
+# tile's accumulators stay resident while every row block streams past them
+# (TPU grids run sequentially, so the read-modify-write accumulation is
+# race-free).  On the VPU path key ids run along sublanes: a VMEM
+# accumulator is (KEY_TILE, 128), one key id per row and one lane partial
+# per lane; after the last row block the kernel folds the lane partials and
+# writes the tile's keys along the lanes of a dense (keys / 128, 128)
+# output, 4 bytes a key.  On the MXU path a key tile's accumulator is its
+# (16, 128) output block itself, key ``hi * 16 + lo`` at [lo, hi % 128].
+# Integer and float accumulators are preserved; sub-f32 floats accumulate
+# in f32 and are cast back.
 from __future__ import annotations
 
 import functools
@@ -80,6 +100,21 @@ _SUBLANES = 8
 KEY_TILE = 64
 _TILES_PER_ROW = _LANES // KEY_TILE  # key tiles per 128-key output row
 _TILES_PER_OUT = _TILES_PER_ROW * _SUBLANES  # ... per (8, 128) output block
+
+# MXU path: key = hi * _LO + lo, one MXU key tile spans 128 values of hi
+_LO_BITS = 4
+_LO = 1 << _LO_BITS
+MXU_KEYS = _LANES * _LO
+# bf16 terms each value dtype enters the MXU as (and only these dtypes)
+_MXU_TERMS = {"float32": 3, "bfloat16": 1, "int32": 4}
+# rows one MXU grid step may stream: a step's count of an int32 byte term
+# (0..255) is exact in f32 while rows x 255 < 2^24, i.e. up to 65,793 rows
+_MXU_STEP_ROWS = 1 << 16
+# the VPU path wins up to this many 64-key tiles: on a v5e, over 2^25 rows,
+# one 2,048-key MXU tile took 8.6-9.1 ms, the VPU sweep 5.3 ms at two 64-key
+# tiles and 9.5-9.6 ms at four (benchmarks/chip_segreduce.py)
+_VPU_MAX_TILES = 3
+_ROWS_NT = (((1,), (1,)), ((), ()))  # contract two operands' lane (row) axes
 
 
 def _combine(op: str, acc, x):
@@ -153,6 +188,154 @@ def _fused_kernel(*refs, ops: Tuple[str, ...], n_vals: int, n_groups: int):
             o_ref[...] = _combine(op, o_ref[...], jnp.where(on_row, row, ident))
 
 
+def kernel_path(ops: Sequence[str], dtypes: Sequence, num_keys: int) -> str:
+    """``'mxu'`` or ``'vpu'``: the path a launch aggregating columns of
+    ``dtypes`` under ``ops`` (presence, an int32 sum, implied) into
+    ``num_keys`` keys takes.  The MXU contracts sums alone, of the dtypes
+    it takes exactly; over a few key tiles the VPU's sweep is cheaper."""
+    if not all(op == "sum" for op in ops):
+        return "vpu"
+    if not all(jnp.dtype(dt).name in _MXU_TERMS for dt in dtypes):
+        return "vpu"
+    return "mxu" if -(-num_keys // KEY_TILE) > _VPU_MAX_TILES else "vpu"
+
+
+def _bf16_high(x):
+    """``x`` cut to its sign, exponent and top 7 mantissa bits: exact in
+    bf16, and never rounded up past bf16's largest to inf."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32) & jnp.int32(-(1 << 16))
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def _mxu_terms(kind: str, v) -> list:
+    """Terms of an (8, 128) value group, each exact in bf16, that sum to it:
+    three for f32 (non-finite values enter as 0), one for a bf16 value
+    (held as f32), four bytes for int32.  An f32 under 2^-110 in magnitude
+    loses the bits of its terms that fall below f32's normal range (2^-126),
+    which the device flushes to zero."""
+    if kind == "int32":
+        return [((v >> (8 * b)) & 255).astype(jnp.float32) for b in range(4)]
+    v = jnp.where(jnp.abs(v) < jnp.inf, v, 0.0)
+    if kind == "bfloat16":
+        return [v]
+    v1 = _bf16_high(v)  # 24 mantissa bits: 8 each in v1, v2 and v3
+    r = v - v1
+    v2 = _bf16_high(r)
+    return [v1, v2, r - v2]
+
+
+def _mxu_kernel(*refs, kinds: Tuple[str, ...], with_presence: bool, n_groups: int):
+    n_vals = len(kinds)
+    keys_ref = refs[0]
+    vals_refs = refs[1 : 1 + n_vals]
+    out_refs = refs[1 + n_vals : 1 + 2 * n_vals + with_presence]
+    # per float value: its Kahan compensation (16, 128) and its non-finite
+    # counts (32, 128): rows [0, 16) +inf or NaN, [16, 32) -inf or NaN
+    floats = [a for a, k in enumerate(kinds) if k != "int32"]
+    scratch = refs[1 + 2 * n_vals + with_presence :]
+    comp = dict(zip(floats, scratch[0::2]))
+    nonfinite = dict(zip(floats, scratch[1::2]))
+    j, i = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(i == 0)
+    def _init():
+        for r in (*out_refs, *scratch):
+            r[...] = jnp.zeros_like(r)
+
+    def rows(g):  # the lane rows of (8, 128) group g of the step
+        return pl.ds(pl.multiple_of(g * _SUBLANES, _SUBLANES), _SUBLANES)
+
+    def contract(terms_of, n_terms):
+        """(16 n_terms, 128) f32: term t of the step's rows summed by key,
+        key hi * 16 + lo of this tile at [16 t + lo, hi % 128]."""
+
+        def group(g, part):
+            keys = keys_ref[rows(g), :]
+            hi = (keys >> _LO_BITS) - j * _LANES  # arithmetic: key -1 has hi -1
+            lo = keys & (_LO - 1)
+            terms = terms_of(g)
+            hi_ids = jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 0)
+            lo_ids = jax.lax.broadcasted_iota(jnp.int32, (_LO, _LANES), 0)
+            for s in range(_SUBLANES):  # 128 rows a matmul, rows on lanes
+                h = jnp.where(hi_ids == hi[s : s + 1, :], 1.0, 0.0).astype(jnp.bfloat16)
+                on_lo = lo_ids == lo[s : s + 1, :]
+                lhs = jnp.concatenate([jnp.where(on_lo, t[s : s + 1, :], 0.0) for t in terms])
+                part = part + jax.lax.dot_general(
+                    lhs.astype(jnp.bfloat16), h, _ROWS_NT, preferred_element_type=jnp.float32
+                )
+            return part
+
+        init = jnp.zeros((_LO * n_terms, _LANES), jnp.float32)
+        return jax.lax.fori_loop(0, n_groups, group, init)
+
+    def value_terms(g):
+        out = []
+        for kind, v_ref in zip(kinds, vals_refs):
+            out += _mxu_terms(kind, v_ref[rows(g), :])
+        if with_presence:
+            out.append(jnp.ones((_SUBLANES, _LANES), jnp.float32))
+        return out
+
+    n_terms = sum(_MXU_TERMS[k] for k in kinds) + with_presence
+    part = contract(value_terms, n_terms)
+    t = 0
+    for a, kind in enumerate(kinds):
+        ps = [part[_LO * (t + b) : _LO * (t + b + 1)] for b in range(_MXU_TERMS[kind])]
+        t += len(ps)
+        o_ref = out_refs[a]
+        if kind == "int32":
+            # exact per-step byte counts; int32 shifts and adds wrap as the
+            # VPU path's int32 adds do
+            o_ref[...] += functools.reduce(
+                jnp.add, [p.astype(jnp.int32) << (8 * b) for b, p in enumerate(ps)]
+            )
+            continue
+        x = functools.reduce(jnp.add, ps[::-1])  # smallest term first
+        y = x - comp[a][...]  # Kahan: the steps' partials fold in compensated
+        acc = o_ref[...]
+        total = acc + y
+        comp[a][...] = (total - acc) - y
+        o_ref[...] = total
+    if with_presence:
+        out_refs[n_vals][...] += part[_LO * t :].astype(jnp.int32)
+
+    if floats:
+
+        def bad_group(g, bad):
+            live = keys_ref[rows(g), :] >= 0
+            for a in floats:
+                odd = live & ~(jnp.abs(vals_refs[a][rows(g), :]) < jnp.inf)
+                bad = bad | jnp.where(odd, 1, 0)
+            return bad
+
+        bad = jax.lax.fori_loop(0, n_groups, bad_group, jnp.zeros((_SUBLANES, _LANES), jnp.int32))
+
+        @pl.when(jnp.max(bad) > 0)
+        def _count_nonfinite():
+            def flag_terms(g):
+                out = []
+                for a in floats:
+                    v = vals_refs[a][rows(g), :]
+                    odd = ~(jnp.abs(v) < jnp.inf)
+                    out.append(jnp.where(odd & (v != -jnp.inf), 1.0, 0.0))
+                    out.append(jnp.where(odd & (v != jnp.inf), 1.0, 0.0))
+                return out
+
+            counts = contract(flag_terms, 2 * len(floats))
+            for f, a in enumerate(floats):
+                nonfinite[a][...] += counts[2 * _LO * f : 2 * _LO * (f + 1)]
+
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _flush():
+        for a in floats:
+            pos = nonfinite[a][:_LO] > 0
+            neg = nonfinite[a][_LO:] > 0
+            total = out_refs[a][...] - comp[a][...]
+            out_refs[a][...] = jnp.where(
+                pos, jnp.where(neg, jnp.nan, jnp.inf), jnp.where(neg, -jnp.inf, total)
+            )
+
+
 def fused_segreduce_pallas(
     keys: jnp.ndarray,
     values: Sequence[jnp.ndarray],
@@ -186,6 +369,9 @@ def fused_segreduce_pallas(
         )
         pres = jnp.zeros((num_keys,), jnp.int32) if with_presence else None
         return accs, pres
+    path = kernel_path(ops, [v.dtype for v in values], num_keys)
+    if path == "mxu":
+        tile = min(tile, _MXU_STEP_ROWS)
     group = _SUBLANES * _LANES
     n_rows = -(-n // group) * _SUBLANES  # lane rows, whole (8, 128) groups
     block_rows = min(max(_SUBLANES, tile // group * _SUBLANES), n_rows)
@@ -202,11 +388,16 @@ def fused_segreduce_pallas(
         keys = jnp.where(mask.astype(bool), keys, -1)  # -1 matches no key id
     keys_2d = lane_dense(keys, -1)  # padding rows are masked rows
     vals_2d = [lane_dense(v.astype(dt), 0) for v, dt in zip(values, dts)]
+    row_block = pl.BlockSpec((block_rows, _LANES), lambda j, i: (i, 0))
+    n_blocks = n_rows // block_rows
+    if path == "mxu":
+        return _mxu_segreduce(
+            keys_2d, vals_2d, values, num_keys, with_presence, row_block, n_blocks, interpret
+        )
     n_tiles = -(-num_keys // KEY_TILE)
     out_rows = -(-n_tiles // _TILES_PER_OUT) * _SUBLANES
     acc_ops = tuple(ops) + (("sum",) if with_presence else ())
     acc_dts = dts + ([jnp.dtype(jnp.int32)] if with_presence else [])
-    row_block = pl.BlockSpec((block_rows, _LANES), lambda j, i: (i, 0))
     # one (8, 128) output block holds the keys of _TILES_PER_OUT key tiles,
     # so it stays resident while they run and is written back once
     out_block = pl.BlockSpec((_SUBLANES, _LANES), lambda j, i: (j // _TILES_PER_OUT, 0))
@@ -214,7 +405,7 @@ def fused_segreduce_pallas(
         functools.partial(
             _fused_kernel, ops=acc_ops, n_vals=n_aggs, n_groups=block_rows // _SUBLANES
         ),
-        grid=(n_tiles, n_rows // block_rows),
+        grid=(n_tiles, n_blocks),
         in_specs=[row_block] * (1 + n_aggs),
         out_specs=tuple(out_block for _ in acc_dts),
         out_shape=tuple(jax.ShapeDtypeStruct((out_rows, _LANES), dt) for dt in acc_dts),
@@ -225,6 +416,38 @@ def fused_segreduce_pallas(
     accs = tuple(o.reshape(-1)[:num_keys].astype(v.dtype) for o, v in zip(outs, values))
     pres = outs[n_aggs].reshape(-1)[:num_keys] if with_presence else None
     return accs, pres
+
+
+def _mxu_segreduce(keys_2d, vals_2d, values, num_keys, with_presence, row_block, n_blocks,
+                   interpret):
+    kinds = tuple(jnp.dtype(v.dtype).name for v in values)
+    n_tiles = -(-num_keys // MXU_KEYS)
+    acc_dts = [jnp.int32 if k == "int32" else jnp.float32 for k in kinds]
+    acc_dts += [jnp.int32] if with_presence else []
+    n_floats = sum(k != "int32" for k in kinds)
+    out_block = pl.BlockSpec((_LO, _LANES), lambda j, i: (0, j))
+    outs = pl.pallas_call(
+        functools.partial(
+            _mxu_kernel, kinds=kinds, with_presence=with_presence,
+            n_groups=row_block.block_shape[0] // _SUBLANES,
+        ),
+        grid=(n_tiles, n_blocks),
+        in_specs=[row_block] * (1 + len(values)),
+        out_specs=tuple(out_block for _ in acc_dts),
+        out_shape=tuple(
+            jax.ShapeDtypeStruct((_LO, n_tiles * _LANES), dt) for dt in acc_dts
+        ),
+        scratch_shapes=[
+            pltpu.VMEM((rows, _LANES), jnp.float32)
+            for _ in range(n_floats) for rows in (_LO, 2 * _LO)
+        ],
+        interpret=interpret,
+        name="segreduce",
+    )(keys_2d, *vals_2d)
+    # [lo, hi] -> key hi * 16 + lo
+    flat = [o.T.reshape(-1)[:num_keys] for o in outs]
+    accs = tuple(f.astype(v.dtype) for f, v in zip(flat, values))
+    return accs, (flat[len(values)] if with_presence else None)
 
 
 def segreduce_pallas(

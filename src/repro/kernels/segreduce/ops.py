@@ -14,7 +14,7 @@ from typing import Optional, Sequence
 
 import jax
 
-from .kernel import fused_segreduce_pallas, segreduce_pallas
+from .kernel import fused_segreduce_pallas, kernel_path, segreduce_pallas
 from .ref import fused_segreduce_ref, segreduce_ref
 
 # rows one grid step of the engine's kernel launches streams (512 lane rows
@@ -63,6 +63,14 @@ def _resolve_mode(use_pallas: Optional[bool]) -> str:
     if not use_pallas:
         return "off"
     return "compiled" if jax.default_backend() == "tpu" else "interpret"
+
+
+def launch_path(ops: Sequence[str], dtypes: Sequence, num_keys: int) -> Optional[str]:
+    """The kernel path ('mxu' or 'vpu', ``kernel.kernel_path``) a launch
+    through these wrappers takes, or None where the jnp fallback runs."""
+    if pallas_mode() == "off":
+        return None
+    return kernel_path(ops, dtypes, num_keys)
 
 
 @partial(jax.jit, static_argnames=("num_keys", "op", "mode"))

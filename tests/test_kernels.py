@@ -15,7 +15,9 @@ except ImportError:
 import jax.numpy as jnp
 
 from repro.kernels.segreduce.kernel import (
+    acc_dtype,
     fused_segreduce_pallas,
+    kernel_path,
     op_identity,
     segreduce_pallas,
 )
@@ -47,19 +49,107 @@ def test_segreduce_sweep(rng, n, k, op):
 
 
 @pytest.mark.parametrize("op", ["sum", "max", "min"])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int32])
-def test_segreduce_dtypes(rng, dtype, op):
+@pytest.mark.parametrize(
+    "dtype,num_keys",
+    [
+        pytest.param(jnp.float32, 33, id="float32"),
+        pytest.param(jnp.bfloat16, 33, id="bfloat16"),
+        pytest.param(jnp.int32, 33, id="int32"),
+        # sums over 2,048 keys (not a multiple of 16) take the MXU path;
+        # int32 values span the whole range, negative ones and sums that wrap
+        pytest.param(jnp.float32, 2100, id="float32-2100keys"),
+        pytest.param(jnp.bfloat16, 2100, id="bfloat16-2100keys"),
+        pytest.param(jnp.int32, 2100, id="int32-2100keys"),
+    ],
+)
+def test_segreduce_dtypes(rng, dtype, num_keys, op):
     """Input dtype is PRESERVED (int32 in → int32 out), with dtype-correct
     identities — int MIN/MAX use the iinfo extremes, not a float sentinel."""
-    keys = jnp.asarray(rng.integers(0, 33, 500), jnp.int32)
-    vals = jnp.asarray(rng.integers(0, 10, 500)).astype(dtype)
-    got = segreduce_pallas(keys, vals, 33, op=op, interpret=True)
-    want = segreduce_ref(keys, vals, 33, op=op)
+    n = 500 if num_keys == 33 else 6000
+    keys = jnp.asarray(rng.integers(0, num_keys, n), jnp.int32)
+    if num_keys == 33:
+        vals = jnp.asarray(rng.integers(0, 10, n)).astype(dtype)
+    elif dtype == jnp.int32:
+        vals = jnp.asarray(rng.integers(-(2**31), 2**31, n, dtype=np.int64).astype(np.int32))
+    else:
+        vals = jnp.asarray(rng.normal(size=n)).astype(dtype)
+    got = segreduce_pallas(keys, vals, num_keys, op=op, interpret=True)
     assert got.dtype == jnp.dtype(dtype)
-    assert want.dtype == jnp.dtype(dtype)
+    assert segreduce_ref(keys, vals, num_keys, op=op).dtype == jnp.dtype(dtype)
+    # the kernel accumulates bf16 in f32 (acc_dtype) and rounds once
+    want = segreduce_ref(keys, vals.astype(acc_dtype(dtype)), num_keys, op=op).astype(dtype)
+    if dtype == jnp.int32:
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     np.testing.assert_allclose(
         np.asarray(got, np.float64), np.asarray(want, np.float64), rtol=1e-2, atol=1e-2
     )
+
+
+@pytest.mark.parametrize(
+    "ops,dtypes,num_keys,path",
+    [
+        (("sum",), ("float32",), 2048, "mxu"),
+        (("sum", "sum"), ("int32", "bfloat16"), 3000, "mxu"),
+        (("sum",), ("int32",), 1 << 20, "mxu"),
+        (("sum",), ("float32",), 64, "vpu"),  # one 64-key tile
+        (("sum",), ("float32",), 1, "vpu"),
+        (("sum",), ("int32",), 192, "vpu"),  # three tiles: the sweep is cheaper
+        (("sum",), ("int32",), 193, "mxu"),
+        (("max",), ("float32",), 2048, "vpu"),
+        (("min",), ("int32",), 3000, "vpu"),
+        (("sum", "max"), ("float32", "float32"), 2048, "vpu"),
+        (("sum",), ("float16",), 2048, "vpu"),  # not a dtype the MXU takes exactly
+    ],
+)
+def test_kernel_path_contracts_wide_sum_groups_on_the_mxu(ops, dtypes, num_keys, path):
+    assert kernel_path(ops, [jnp.dtype(d) for d in dtypes], num_keys) == path
+
+
+@pytest.mark.parametrize("value", [255, -1])
+def test_mxu_byte_counts_exact_at_a_full_step(value):
+    """A whole 2^16-row grid step of one key: each byte term's count reaches
+    2^16 x 255, just under 2^24, and stays exact in f32; the int32 sum
+    wraps as an int32 add does (-1 is four bytes of 255)."""
+    n, num_keys = 1 << 16, 2100
+    keys = jnp.full((n,), 2099, jnp.int32)
+    vals = jnp.full((n,), value, jnp.int32)
+    (acc,), pres = fused_segreduce_pallas(
+        keys, (vals,), ("sum",), num_keys, tile=1 << 16, interpret=True
+    )
+    assert int(acc[2099]) == np.int32(np.int64(value) * n)
+    assert int(pres[2099]) == n
+    assert not np.asarray(acc[:2099]).any() and not np.asarray(pres[:2099]).any()
+
+
+def test_mxu_non_finite_values_stay_in_their_key():
+    """+inf, -inf and NaN sum as IEEE adds do, in their own key only: a
+    NaN times the one-hot's zeros must not reach the keys beside it, nor a
+    masked row's NaN any key.  A finite value above bf16's largest (key
+    10) splits into finite terms too."""
+    num_keys = 2100
+    keys = np.arange(4000, dtype=np.int32) % num_keys
+    vals = np.ones(4000, np.float32)
+    odd = {5: [np.inf], 6: [-np.inf], 7: [np.nan], 8: [np.inf, -np.inf], 9: [np.inf, np.inf],
+           10: [np.finfo(np.float32).max]}
+    at = 0
+    for k, vs in odd.items():
+        for v in vs:
+            keys[at], vals[at] = k, v
+            at += 1
+    mask = np.ones(4000, bool)
+    keys[at], vals[at], mask[at] = 17, np.nan, False
+    (acc,), _ = fused_segreduce_pallas(
+        jnp.asarray(keys), (jnp.asarray(vals),), ("sum",), num_keys,
+        mask=jnp.asarray(mask), interpret=True,
+    )
+    (want,), _ = fused_segreduce_ref(
+        jnp.asarray(keys), (jnp.asarray(vals),), ("sum",), num_keys, mask=jnp.asarray(mask)
+    )
+    got = np.asarray(acc)
+    assert kernel_path(("sum",), [jnp.float32], num_keys) == "mxu"
+    assert np.isposinf(got[5]) and np.isneginf(got[6]) and np.isnan(got[7:9]).all()
+    assert np.isposinf(got[9])
+    np.testing.assert_allclose(np.delete(got, [5, 6, 7, 8, 9]), np.delete(np.asarray(want), [5, 6, 7, 8, 9]))
 
 
 def test_segreduce_int_extremes_identity():
@@ -172,12 +262,16 @@ def test_pallas_mode(monkeypatch, backend, env, want):
 # ---------------------------------------------------------------------------
 
 # (n rows, num_keys, key range) — TILE=1024 ⇒ multi_tile spans 5 row tiles,
-# and empty_groups leaves keys [8, 64) with no rows at all
+# and empty_groups leaves keys [8, 64) with no rows at all.  The sums of the
+# last two take the MXU path: one 2,048-key tile, then two, over key spaces
+# that are no multiple of 16
 _SHAPES = {
     "empty_table": (0, 16, 16),
     "empty_groups": (200, 64, 8),
     "single_tile": (300, 16, 16),
     "multi_tile": (5000, 16, 16),
+    "mxu_one_key_tile": (3000, 1000, 1000),
+    "mxu_key_tiles": (5000, 3000, 3000),
 }
 
 _MERGE_NP = {"sum": np.add, "max": np.maximum, "min": np.minimum}

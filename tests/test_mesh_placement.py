@@ -58,6 +58,7 @@ def report(plan, rows, db, sql):
                           "devices": len(c.sharding.device_set)}
                       for f, c in placed.get("t", {}).items()},
         "upload_bytes": plan.upload_bytes,
+        "segreduce_path": plan.lowering.segreduce_path,
     }
 
 QUERIES = {
@@ -85,6 +86,14 @@ for name in ("sum", "max"):
         n_parts=4, agg_method="kernel", parallel_exec="shard_map", mesh=mesh))
     out["kernel_" + name] = report(res.plan, res.plan.run()["R"], s.db, sql)
 
+# ... and over 3,000 keys, where each device contracts the sum on the MXU
+w = Session(mesh=mesh, n_parts=4, revalidate="signature")
+w.register("t", **dict(cols, k=rng.integers(0, 3000, ROWS).astype(np.int32)))
+sql = QUERIES["sum"]
+res = optimize(sql_to_forelem(sql, SCHEMA), w.db, OptimizeOptions(
+    n_parts=4, agg_method="kernel", parallel_exec="shard_map", mesh=mesh))
+out["kernel_sum_mxu"] = report(res.plan, res.plan.run()["R"], w.db, sql)
+
 # a row count the mesh divides: no padding
 s = Session(mesh=mesh, n_parts=4, revalidate="signature")
 s.register("t", **{c: a[: 1 << 18] for c, a in cols.items()})
@@ -93,8 +102,10 @@ out["divisible"] = report(r.plan, r.rows, s.db, QUERIES["sum"])
 print(json.dumps(out))
 """
 
-CASES = ("sum", "count", "min", "max", "filtered", "kernel_sum", "kernel_max", "divisible")
-ON_MESH = ("sum", "count", "min", "max", "kernel_sum", "kernel_max", "divisible")
+CASES = ("sum", "count", "min", "max", "filtered", "kernel_sum", "kernel_max", "divisible",
+         "kernel_sum_mxu")
+ON_MESH = ("sum", "count", "min", "max", "kernel_sum", "kernel_max", "divisible",
+           "kernel_sum_mxu")
 
 
 @pytest.fixture(scope="module")
@@ -141,6 +152,15 @@ def test_one_combine_per_accumulator(mesh_report, case):
     assert r["data_movement"] == 0
     # COUNT's value and presence are the same array, which XLA reduces once
     assert r["allreduce_operands"] == (1 if case == "count" else 2)
+
+
+@pytest.mark.parametrize(
+    "case,path", [("sum", []), ("kernel_sum", ["vpu", "vpu"]), ("kernel_max", ["vpu", "vpu"]),
+                  ("kernel_sum_mxu", ["mxu", "mxu"])]
+)
+def test_kernel_path_of_each_launch_on_the_mesh(mesh_report, case, path):
+    # the aggregate's launch and the presence count's, on every device
+    assert mesh_report[case]["segreduce_path"] == path
 
 
 @pytest.mark.parametrize("case", ("sum", "max"))

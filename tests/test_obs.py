@@ -285,6 +285,25 @@ def test_join_probe_counter_names_the_probe(backend, method):
     assert not any(k.startswith("join.probe") and method not in k for k in counters)
 
 
+@pytest.mark.parametrize("agg,path", [("SUM", "mxu"), ("MAX", "vpu")])
+def test_segreduce_path_counter_names_the_kernel_path(monkeypatch, agg, path):
+    # priced as on a TPU, the planner sends a 300-key GROUP BY to the
+    # segreduce kernel (interpreted here): a sum group is contracted on the
+    # MXU, a max group swept on the VPU, one launch a query
+    from repro.planner import cost
+
+    monkeypatch.setattr(cost, "pallas_mode", lambda: "compiled")
+    monkeypatch.setenv("REPRO_PALLAS", "1")
+    s = Session(backend="jax")
+    s.register("t", **_cols(key_range=300))
+    for _ in range(2):
+        s.sql(f"SELECT k, {agg}(v) FROM t GROUP BY k")
+    counters = s.metrics_registry.snapshot()["counters"]
+    assert {k: v for k, v in counters.items() if k.startswith("segreduce.path")} == {
+        f"segreduce.path{{path={path}}}": 2
+    }
+
+
 def test_table_replacement_counts_invalidations():
     s = _session()
     s.sql(Q)

@@ -1,8 +1,9 @@
 # Compile the engine's device programs at real widths for a described TPU
 # v5e, with no chip attached: the Mosaic-compiled segreduce kernel at 2^25
-# rows and 3000 or 2^20 keys, and a whole expand-join plan.  What interpret mode
-# cannot show — an op Mosaic refuses, a layout that inflates HBM use, a
-# program that does not fit the chip — fails here.
+# rows and 2048, 3000 or 2^20 keys on both of its paths, and a whole
+# expand-join plan.  What interpret mode cannot show — an op Mosaic refuses,
+# a layout that inflates HBM use, a program that does not fit the chip —
+# fails here.
 #
 # The topology is described inside a module-scoped fixture, never while a
 # module is imported: only one process may load the TPU library at a time.
@@ -19,7 +20,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceShardin
 
 from repro import OptimizeOptions, optimize, sql_to_forelem
 from repro.data.multiset import Database, Multiset
-from repro.kernels.segreduce.kernel import fused_segreduce_pallas
+from repro.kernels.segreduce.kernel import fused_segreduce_pallas, kernel_path
 from repro.kernels.segreduce.ops import ENGINE_TILE
 
 ROWS = 1 << 25
@@ -100,6 +101,18 @@ def test_segreduce_kernel_output_dense_at_large_key_space(
     take 512 MiB of temp per accumulator."""
     mem = _compile_kernel(one_chip, 1 << 20, dtypes, ops, with_presence, masked)
     assert mem.temp_size_in_bytes <= 2 * mem.argument_size_in_bytes
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["unmasked", "masked"])
+@pytest.mark.parametrize("num_keys", [2048, 3000])
+def test_segreduce_mxu_path_compiles_and_fits(one_chip, num_keys, masked):
+    """The BDB aggregation's launch (an f32 SUM and the presence count) on
+    the MXU path: the row contraction's operand layouts compile, and the
+    one-hots stay in VMEM — HBM holds at most the masked key column beside
+    the arguments, where a one-hot of 2^25 rows would take gigabytes."""
+    assert kernel_path(("sum",), [jnp.float32], num_keys) == "mxu"
+    mem = _compile_kernel(one_chip, num_keys, (jnp.float32,), ("sum",), True, masked)
+    assert mem.temp_size_in_bytes <= (4 * ROWS if masked else 0) + (1 << 20)
 
 
 def test_expand_join_plan_fits_one_chip(one_chip):
