@@ -1,8 +1,8 @@
 # Kernel micro-benchmarks.  On this CPU container the *jnp reference paths*
 # are timed (wall-clock of Pallas interpret mode measures the Python
-# interpreter, not the kernel); the Pallas kernels themselves are validated
-# for correctness in tests/ and characterized structurally in the roofline
-# report.  derived = achieved GB/s or GFLOP/s of the jnp path on CPU.
+# interpreter, not the kernel); the Pallas kernel itself is validated for
+# correctness in tests/ and timed on the chip by chip_segreduce.py.
+# derived = achieved GB/s of the jnp path on CPU.
 #
 # The fused-segreduce section benchmarks the PR's claim directly: the fused
 # multi-aggregate path (one data pass, aggregates stacked per op/dtype
@@ -161,48 +161,6 @@ def run() -> List[Tuple[str, float, str]]:
     t = _timeit(lambda: f(keys, vals))
     gbps = (n * 8) / t / 1e9
     out.append(("kernel_segreduce_ref_4M", t * 1e6, f"{gbps:.2f}GB/s"))
-
-    # flash attention fwd: B2 S2048 H8 D64 (jnp online-softmax path)
-    from repro.models.attention import flash_attention_jnp
-
-    B, S, H, Hkv, D = 2, 2048, 8, 4, 64
-    q = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.bfloat16)
-    kk = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.bfloat16)
-    v = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.bfloat16)
-    f2 = jax.jit(lambda q, k, v: flash_attention_jnp(q, k, v, causal=True, scale=D ** -0.5))
-    t = _timeit(lambda: f2(q, kk, v))
-    flops = 4 * B * H * S * S * D / 2  # causal half
-    out.append(("kernel_flash_jnp_2k", t * 1e6, f"{flops/t/1e9:.1f}GFLOP/s"))
-
-    # banded (sliding-window) vs full attention at 8k — the sub-quadratic win
-    from repro.models.attention import banded_window_attention
-
-    S2, W = 8192, 1024
-    q2 = jnp.asarray(rng.normal(size=(1, S2, H, D)), jnp.bfloat16)
-    k2 = jnp.asarray(rng.normal(size=(1, S2, Hkv, D)), jnp.bfloat16)
-    v2 = jnp.asarray(rng.normal(size=(1, S2, Hkv, D)), jnp.bfloat16)
-    fb = jax.jit(lambda q, k, v: banded_window_attention(q, k, v, window=W, scale=D ** -0.5))
-    tb = _timeit(lambda: fb(q2, k2, v2))
-    ff = jax.jit(lambda q, k, v: flash_attention_jnp(q, k, v, causal=True, scale=D ** -0.5))
-    tf = _timeit(lambda: ff(q2, k2, v2))
-    out.append(("kernel_banded_window_8k_w1k", tb * 1e6, f"{tf/tb:.2f}x_vs_full"))
-
-    # wkv6: chunked vs per-token scan (the kernel's HBM-traffic claim)
-    from repro.models import rwkv6 as R
-
-    B3, S3, H3, K3 = 2, 2048, 8, 64
-    r = jnp.asarray(rng.normal(size=(B3, S3, H3, K3)), jnp.float32) * 0.5
-    k3 = jnp.asarray(rng.normal(size=(B3, S3, H3, K3)), jnp.float32) * 0.5
-    v3 = jnp.asarray(rng.normal(size=(B3, S3, H3, K3)), jnp.float32) * 0.5
-    lw = -jnp.exp(jnp.asarray(rng.normal(size=(B3, S3, H3, K3)), jnp.float32) * 0.3 - 2)
-    u = jnp.asarray(rng.normal(size=(H3, K3)), jnp.float32) * 0.3
-    S0 = jnp.zeros((B3, H3, K3, K3), jnp.float32)
-    f_scan = jax.jit(lambda *a: R._wkv_scan(*a)[0])
-    f_chun = jax.jit(lambda *a: R._wkv_chunked(*a)[0])
-    ts = _timeit(lambda: f_scan(r, k3, v3, lw, u, S0))
-    tc = _timeit(lambda: f_chun(r, k3, v3, lw, u, S0))
-    out.append(("kernel_wkv6_scan_2k", ts * 1e6, "1.0x"))
-    out.append(("kernel_wkv6_chunked_2k", tc * 1e6, f"{ts/tc:.2f}x_vs_scan"))
 
     with open("BENCH_kernels.json", "w") as fh:
         json.dump(report, fh, indent=2)

@@ -35,7 +35,6 @@ def main(argv=None) -> int:
         bench_join,
         bench_kernels,
         bench_partition,
-        bench_pipeline,
         bench_planner,
         bench_sched,
         bench_serve,
@@ -46,7 +45,6 @@ def main(argv=None) -> int:
         ("fig2", bench_fig2.run),
         ("kernels", bench_kernels.run),
         ("sched", bench_sched.run),
-        ("pipeline", bench_pipeline.run),
         ("planner", bench_planner.run),
         ("join", bench_join.run),
         ("engine", bench_engine.run),

@@ -8,10 +8,10 @@
 #      adjacent foralls on the same multiset, and resolves them by statement
 #      reordering + Loop Fusion (the paper's two-aggregate example),
 #      including the congruence-witnessed case (A.field1 ≡ A.field2).
-#   2. A generic chain sharding solver (Viterbi DP) that the LM launcher
-#      uses to pick tensor shardings that minimize modeled resharding cost
-#      between consecutive program stages — the same §III-A4 objective
-#      applied to the training/serving computation graph.
+#   2. A generic chain sharding solver (Viterbi DP) that picks per-stage
+#      tensor shardings minimizing modeled resharding cost between
+#      consecutive program stages — the same §III-A4 objective applied to
+#      a chain of stages.  No engine module calls it yet.
 from __future__ import annotations
 
 import dataclasses
@@ -145,7 +145,7 @@ def optimize_distribution(
 
 
 # ===========================================================================
-# 2. Generic chain sharding solver (used by the LM launcher)
+# 2. Generic chain sharding solver
 # ===========================================================================
 
 

@@ -1,4 +1,4 @@
-# Scheduling policies shared by the query engine and the LM launch stack:
+# Scheduling policies of the query engine:
 # loop_schedule (chunk dispatch order/sizes for the partitioned backend),
 # fault_tolerant (bounded chunk retry, straggler speculation, injectable
 # faults — the QueryServer's dispatch guarantees), elastic (worker-pool

@@ -1,5 +1,5 @@
-# NOTE: no XLA_FLAGS here on purpose — smoke tests and benches must see the
-# real single CPU device; only launch/dryrun.py forces 512 host devices.
+# NOTE: no XLA_FLAGS here on purpose — tests and benches see the real single
+# CPU device; a test that needs a mesh forces host devices in a subprocess.
 import os
 
 import numpy as np

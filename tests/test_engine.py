@@ -317,3 +317,33 @@ def test_scalar_and_ordered_results(web_session):
     top = s.sql("SELECT url, COUNT(url) AS c FROM access GROUP BY url ORDER BY c DESC LIMIT 3")
     counts = sorted(np.unique(urls, return_counts=True)[1], reverse=True)[:3]
     assert [c for _, c in top.rows] == [int(c) for c in counts]
+
+
+# ---------------------------------------------------------------------------
+# end to end: data → IR → optimize (reformat + parallelize) → execute
+# ---------------------------------------------------------------------------
+
+
+def test_full_bigdata_session():
+    """SQL session over weblogs: optimize (reformat+parallelize) and check
+    answers against numpy on the raw strings."""
+    from repro.core import OptimizeOptions, optimize
+    from repro.data.multiset import Database, Multiset, PlainColumn
+    from repro.frontends.sql import sql_to_forelem
+
+    rng = np.random.default_rng(0)
+    n = 20_000
+    urls = np.array([f"http://s{u%31}.com" for u in rng.zipf(1.5, n) % 500], dtype=object)
+    status = rng.choice([200, 404, 500], n).astype(np.int32)
+    db = Database().add(Multiset("logs", {"url": PlainColumn(urls), "status": PlainColumn(status)}))
+    schemas = {"logs": ["url", "status"]}
+
+    res = optimize(sql_to_forelem("SELECT status, COUNT(status) FROM logs GROUP BY status", schemas),
+                   db, OptimizeOptions(n_parts=4))
+    got = dict(res.plan.run()["R"])
+    vals, counts = np.unique(status, return_counts=True)
+    assert got == {int(v): int(c) for v, c in zip(vals, counts)}
+
+    res2 = optimize(sql_to_forelem("SELECT SUM(status) FROM logs WHERE status = 500", schemas),
+                    res.db, OptimizeOptions(n_parts=1, reformat=False))
+    assert res2.plan.run()["scalar"] == int(status[status == 500].sum())

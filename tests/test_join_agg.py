@@ -7,6 +7,7 @@
 import numpy as np
 import pytest
 
+from ab_tables import SCHEMAS, make_db, ref_rows
 from repro.core import OptimizeOptions, optimize
 from repro.core.lower import (
     CodegenChoices,
@@ -21,35 +22,6 @@ from repro.frontends.sql import SQLError, sql_to_forelem
 from repro.planner import PlanCache, collect_stats, plan_query
 
 AGG_METHODS = ("dense", "onehot", "sort", "kernel")
-
-SCHEMAS = {"A": ["b_id", "f", "w"], "B": ["id", "g", "v"]}
-
-
-def make_db(rng, n_a=120, n_b=40, key_range=12, dup_build=True):
-    """A (probe/fact) rows point into B (build/dim); dup_build repeats B
-    keys so the build side has multiplicity > 1."""
-    b_keys = (
-        rng.integers(0, key_range, n_b).astype(np.int32)
-        if dup_build
-        else rng.permutation(n_b).astype(np.int32)
-    )
-    A = Multiset.from_columns(
-        "A",
-        b_id=rng.integers(0, key_range if dup_build else n_b, n_a).astype(np.int32),
-        f=rng.integers(0, 6, n_a).astype(np.int32),
-        w=rng.integers(-50, 50, n_a).astype(np.int32),
-    )
-    B = Multiset.from_columns(
-        "B",
-        id=b_keys,
-        g=rng.integers(0, 5, n_b).astype(np.int32),
-        v=rng.integers(-30, 30, n_b).astype(np.int32),
-    )
-    return Database().add(A).add(B)
-
-
-def ref_rows(p, db, params=None):
-    return sorted(ReferenceInterpreter(db, params).run(p)["R"])
 
 
 # ---------------------------------------------------------------------------

@@ -22,10 +22,6 @@ from repro.kernels.segreduce.kernel import (
     segreduce_pallas,
 )
 from repro.kernels.segreduce.ref import fused_segreduce_ref, segreduce_ref
-from repro.kernels.flash.kernel import flash_attention_pallas
-from repro.kernels.flash.ref import attention_ref
-from repro.kernels.wkv6.kernel import wkv6_pallas
-from repro.kernels.wkv6.ref import wkv6_ref
 
 # ---------------------------------------------------------------------------
 # segreduce
@@ -521,109 +517,3 @@ def test_onehot_min_fallback_is_loud(rng):
     )
     res = optimize(p, db, OptimizeOptions(agg_method="onehot", trace=True))
     assert any("aggregation-method fallback" in t for t in res.trace)
-
-
-# ---------------------------------------------------------------------------
-# flash attention
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("S,D,Hkv", [(64, 32, 2), (128, 64, 4), (200, 16, 1)])
-@pytest.mark.parametrize("causal,window,cap", [
-    (True, 0, 0.0), (False, 0, 0.0), (True, 32, 0.0), (True, 0, 30.0),
-])
-def test_flash_sweep(rng, S, D, Hkv, causal, window, cap):
-    B, H = 2, Hkv * 2
-    q = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
-    got = flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                 scale=D ** -0.5, logit_softcap=cap,
-                                 q_block=64, kv_block=64, interpret=True)
-    want = attention_ref(q, k, v, causal=causal, window=window, scale=D ** -0.5, logit_softcap=cap)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3)
-
-
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-3), (jnp.bfloat16, 3e-2)])
-def test_flash_dtypes(rng, dtype, tol):
-    B, S, H, Hkv, D = 1, 96, 4, 2, 32
-    q = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype)
-    k = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), dtype)
-    v = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), dtype)
-    got = flash_attention_pallas(q, k, v, scale=D ** -0.5, q_block=32, kv_block=32, interpret=True)
-    want = attention_ref(q, k, v, scale=D ** -0.5)
-    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), rtol=tol, atol=tol)
-
-
-def test_flash_decode_offset(rng):
-    """Sq < Sk (query block at the end of the key range — decode style)."""
-    B, Sq, Sk, H, Hkv, D = 1, 8, 128, 4, 2, 32
-    q = jnp.asarray(rng.normal(size=(B, Sq, H, D)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, Sk, Hkv, D)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, Sk, Hkv, D)), jnp.float32)
-    got = flash_attention_pallas(q, k, v, causal=True, scale=D ** -0.5,
-                                 q_block=8, kv_block=32, interpret=True)
-    want = attention_ref(q, k, v, causal=True, scale=D ** -0.5)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3)
-
-
-def test_flash_matches_model_attention(rng):
-    """The Pallas kernel and the model's scan-flash agree."""
-    from repro.models.attention import flash_attention_jnp
-
-    B, S, H, Hkv, D = 2, 160, 8, 4, 32
-    q = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
-    a = flash_attention_pallas(q, k, v, scale=D ** -0.5, q_block=64, kv_block=64, interpret=True)
-    b = flash_attention_jnp(q, k, v, causal=True, scale=D ** -0.5, q_block=64, kv_block=64)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3)
-
-
-# ---------------------------------------------------------------------------
-# wkv6
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("S", [16, 100, 256])
-@pytest.mark.parametrize("K", [16, 64])
-@pytest.mark.parametrize("chunk", [16, 32])
-def test_wkv6_sweep(rng, S, K, chunk):
-    B, H = 2, 3
-    r = jnp.asarray(rng.normal(size=(B, S, H, K)), jnp.float32) * 0.5
-    k = jnp.asarray(rng.normal(size=(B, S, H, K)), jnp.float32) * 0.5
-    v = jnp.asarray(rng.normal(size=(B, S, H, K)), jnp.float32) * 0.5
-    lw = -jnp.exp(jnp.asarray(rng.normal(size=(B, S, H, K)), jnp.float32))
-    u = jnp.asarray(rng.normal(size=(H, K)), jnp.float32) * 0.3
-    got = wkv6_pallas(r, k, v, lw, u, chunk=chunk, interpret=True)
-    want, _ = wkv6_ref(r, k, v, lw, u)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3)
-
-
-def test_wkv6_strong_decay_exactness(rng):
-    """Strong decay (w ≈ 0) is the numerically-dangerous regime for chunked
-    forms; the log-space pairwise formulation must stay exact."""
-    B, S, H, K = 1, 64, 2, 16
-    r = jnp.asarray(rng.normal(size=(B, S, H, K)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, S, H, K)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, S, H, K)), jnp.float32)
-    lw = jnp.full((B, S, H, K), -5.0)  # decay e^-5 per token
-    u = jnp.zeros((H, K), jnp.float32)
-    got = wkv6_pallas(r, k, v, lw, u, chunk=16, interpret=True)
-    want, _ = wkv6_ref(r, k, v, lw, u)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
-
-
-def test_wkv6_model_chunked_matches_kernel(rng):
-    from repro.models.rwkv6 import _wkv_chunked
-
-    B, S, H, K = 2, 80, 2, 16
-    r = jnp.asarray(rng.normal(size=(B, S, H, K)), jnp.float32) * 0.5
-    k = jnp.asarray(rng.normal(size=(B, S, H, K)), jnp.float32) * 0.5
-    v = jnp.asarray(rng.normal(size=(B, S, H, K)), jnp.float32) * 0.5
-    lw = -jnp.exp(jnp.asarray(rng.normal(size=(B, S, H, K)), jnp.float32))
-    u = jnp.asarray(rng.normal(size=(H, K)), jnp.float32) * 0.3
-    S0 = jnp.zeros((B, H, K, K), jnp.float32)
-    a = wkv6_pallas(r, k, v, lw, u, chunk=16, interpret=True)
-    b, _ = _wkv_chunked(r, k, v, lw, u, S0, chunk=16)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)

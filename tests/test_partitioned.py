@@ -1,15 +1,18 @@
 # Differential tests for the partitioned executor backend
-# (backends/partitioned.py): every core query shape from test_join_agg.py
-# run over K-way hash/range-partitioned data with scheduled chunk dispatch
-# must equal the reference interpreter bit-for-bit — duplicate-key joins,
-# filtered groups, empty partitions, empty build sides included — plus the
-# planner's (K, schedule) decision and the shard_map max/min bugfix.
+# (backends/partitioned.py): query shapes run over K-way hash/range-
+# partitioned data with scheduled chunk dispatch must equal the reference
+# interpreter bit-for-bit — unique build keys, schedule policies, filtered
+# groups, empty partitions, empty build sides — plus the planner's
+# (K, schedule) decision and the shard_map max/min bugfix.  The core query
+# shapes on every K (and on every other execution path) are in
+# test_paths.py.
 import numpy as np
 import pytest
 
 import jax
 from jax.sharding import Mesh
 
+from ab_tables import CORE_QUERIES, SCHEMAS, make_db, ref_rows
 from repro.backends import (
     CodegenChoices,
     PartitionedChoices,
@@ -24,33 +27,7 @@ from repro.engine import Session
 from repro.frontends.sql import sql_to_forelem
 from repro.planner import DbStats, FieldStats, PlanCache, TableStats, plan_query
 
-SCHEMAS = {"A": ["b_id", "f", "w"], "B": ["id", "g", "v"]}
 KS = (1, 3, 8)
-
-
-def make_db(rng, n_a=120, n_b=40, key_range=12, dup_build=True):
-    b_keys = (
-        rng.integers(0, key_range, n_b).astype(np.int32)
-        if dup_build
-        else rng.permutation(n_b).astype(np.int32)
-    )
-    A = Multiset.from_columns(
-        "A",
-        b_id=rng.integers(0, key_range if dup_build else n_b, n_a).astype(np.int32),
-        f=rng.integers(0, 6, n_a).astype(np.int32),
-        w=rng.integers(-50, 50, n_a).astype(np.int32),
-    )
-    B = Multiset.from_columns(
-        "B",
-        id=b_keys,
-        g=rng.integers(0, 5, n_b).astype(np.int32),
-        v=rng.integers(-30, 30, n_b).astype(np.int32),
-    )
-    return Database().add(A).add(B)
-
-
-def ref_rows(p, db, params=None):
-    return sorted(ReferenceInterpreter(db, params).run(p)["R"])
 
 
 def part_rows(p, db, k, schedule="static", **choice_kw):
@@ -58,32 +35,6 @@ def part_rows(p, db, k, schedule="static", **choice_kw):
         p, db, PartitionedChoices(n_partitions=k, schedule=schedule, **choice_kw)
     )
     return sorted(plan.run()["R"])
-
-
-# ---------------------------------------------------------------------------
-# the core differential matrix (test_join_agg shapes) × K ∈ {1, 3, 8}
-# ---------------------------------------------------------------------------
-
-CORE_QUERIES = [
-    # duplicate-key equi-join (fan-out > 1)
-    "SELECT a.f, b.g FROM A a, B b WHERE a.b_id = b.id",
-    # probe-side residual filter
-    "SELECT a.f, b.g FROM A a, B b WHERE a.b_id = b.id AND a.w > 0",
-    # GROUP BY over a two-table join, keys on either side
-    "SELECT a.f, COUNT(a.f) FROM A a, B b WHERE a.b_id = b.id GROUP BY a.f",
-    "SELECT a.f, SUM(b.v) FROM A a, B b WHERE a.b_id = b.id GROUP BY a.f",
-    "SELECT b.g, COUNT(b.g), SUM(a.w) FROM A a, B b WHERE a.b_id = b.id GROUP BY b.g",
-    "SELECT b.g, MIN(a.w), MAX(b.v) FROM A a, B b WHERE a.b_id = b.id GROUP BY b.g",
-    "SELECT a.f, SUM(a.w + b.v) FROM A a, B b WHERE a.b_id = b.id GROUP BY a.f",
-]
-
-
-@pytest.mark.parametrize("k", KS)
-@pytest.mark.parametrize("sql", CORE_QUERIES)
-def test_core_matrix_matches_reference(rng, sql, k):
-    db = make_db(rng)
-    p = sql_to_forelem(sql, SCHEMAS)
-    assert part_rows(p, db, k) == ref_rows(p, db)
 
 
 @pytest.mark.parametrize("k", KS)

@@ -1,8 +1,6 @@
-# Data pipeline (tokenize/pack/load) and reformatting (§III-C1) invariants.
+# Reformatting (§III-C1) invariants: dictionary encoding, range
+# compression, the reformat planner and its amortization gate.
 import numpy as np
-import pytest
-hypothesis = pytest.importorskip("hypothesis")
-from hypothesis import given, settings, strategies as st
 
 from repro.core import OptimizeOptions, optimize
 from repro.core.reformat import apply_reformat, plan_reformat
@@ -14,7 +12,6 @@ from repro.data.multiset import (
     PlainColumn,
     dict_encode,
 )
-from repro.data.pipeline import PipelineConfig, ShardedLoader, Vocab, build_dataset, build_vocab, tokenize
 from repro.frontends.sql import sql_to_forelem
 
 
@@ -83,50 +80,3 @@ def test_optimize_reformats_then_answers_match_python(rng):
     want = {u: c for u, c in zip(*np.unique(urls, return_counts=True))}
     for code, count in got:
         assert want[dcol.dictionary[code]] == count
-
-
-# ---------------------------------------------------------------------------
-# LM pipeline
-# ---------------------------------------------------------------------------
-
-
-@settings(max_examples=15, deadline=None)
-@given(
-    n_docs=st.integers(1, 60),
-    seq_len=st.sampled_from([32, 64, 128]),
-    seed=st.integers(0, 99),
-)
-def test_property_packing_invariants(n_docs, seq_len, seed):
-    rng = np.random.default_rng(seed)
-    docs = [" ".join(f"w{x}" for x in rng.integers(0, 50, rng.integers(1, 80))) for _ in range(n_docs)]
-    ds = build_dataset(docs, PipelineConfig(seq_len=seq_len, min_doc_tokens=4, vocab_size=256))
-    # all ids within vocab; pad only at the tail row; loss mask matches pad
-    assert ds.tokens.max() < ds.vocab.size
-    assert ds.tokens.min() >= 0
-    assert ((ds.tokens == Vocab.PAD) == ~ds.loss_mask).all()
-    assert ds.tokens.shape[1] == seq_len
-    # token conservation: every kept doc contributes len+2 tokens
-    kept = [d for d in docs if len(d.split()) >= 4]
-    expect = sum(len(d.split()) + 2 for d in kept)
-    assert ds.loss_mask.sum() == expect
-
-
-def test_vocab_specials_and_unk():
-    v = build_vocab(["a b c a"], max_size=6)
-    assert v.id_to_token[:4] == ["<pad>", "<bos>", "<eos>", "<unk>"]
-    ids = tokenize("a z", v)
-    assert ids[0] >= 4 and ids[1] == Vocab.UNK
-
-
-def test_loader_determinism_and_sharding():
-    rng = np.random.default_rng(0)
-    docs = [" ".join(f"w{x}" for x in rng.integers(0, 50, 60)) for _ in range(100)]
-    ds = build_dataset(docs, PipelineConfig(seq_len=64, min_doc_tokens=4))
-    l1 = ShardedLoader(ds, global_batch=8, n_shards=4, shard=1, seed=7)
-    l2 = ShardedLoader(ds, global_batch=8, n_shards=4, shard=1, seed=7)
-    b1, b2 = l1.batch(3), l2.batch(3)
-    np.testing.assert_array_equal(b1["tokens"], b2["tokens"])
-    s = l1.shard_slice(b1)
-    assert s["tokens"].shape[0] == 2
-    chunks = l1.chunks(total_steps=10, chunk_size=4)
-    assert chunks == [(0, 4), (4, 4), (8, 2)]
