@@ -6,6 +6,7 @@
 # generated-MPI-code analogue).
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -24,7 +25,7 @@ from repro.core.ir import (
     Var,
     apply_order_limit,
 )
-from repro.data.multiset import Database, DictColumn
+from repro.data.multiset import Database, DictColumn, Multiset
 
 from repro.kernels.segreduce import ops as segops
 
@@ -42,6 +43,7 @@ from .codegen import (
     required_columns,
 )
 from .interface import register_backend
+from .resident import Key, ResidentColumns
 
 # engine accumulate-op spelling -> segreduce kernel spelling
 _KERNEL_OPS = {"+": "sum", "max": "max", "min": "min"}
@@ -869,8 +871,15 @@ class Plan:
     densifies multiset results back to Python tuples (for comparison with the
     reference interpreter); ``fn`` is the raw jitted callable.
 
-    ``upload_bytes``: the bytes the last ``input_columns`` placed, by
-    placement (``sharded`` over the mesh, ``single`` on the default device)."""
+    ``resident``: the ``ResidentColumns`` store a ``Session`` attaches; with
+    it, a column an earlier plan placed is read from the device instead of
+    copied again.  A plan run outside a session places every column on
+    every run.
+
+    ``upload_bytes``: the bytes the last ``input_columns`` placed, and
+    ``resident_bytes`` the bytes it served from the store, by placement
+    (``sharded`` over the mesh, ``single`` on the default device);
+    ``resident_reads``: its columns by ``hit``/``miss`` in the store."""
 
     def __init__(self, program: Program, db: Database, choices: Optional[CodegenChoices] = None, jit: bool = True):
         self.program = program
@@ -884,7 +893,10 @@ class Plan:
             if self.lowering.mesh_rows
             else None
         )
+        self.resident: Optional[ResidentColumns] = None
         self.upload_bytes: Dict[str, int] = {}
+        self.resident_bytes: Dict[str, int] = {}
+        self.resident_reads: Dict[str, int] = {}
 
     @property
     def n_devices(self) -> int:
@@ -893,27 +905,43 @@ class Plan:
         return 1 if s is None else s.mesh.shape[self.lowering.choices.axis_name]
 
     def input_columns(self) -> Dict[str, Dict[str, jnp.ndarray]]:
-        cols: Dict[str, Dict[str, jnp.ndarray]] = {}
-        placed = {"sharded": 0, "single": 0}
+        wanted: Dict[Key, Callable[[], jax.Array]] = {}
+        where: Dict[Key, Tuple[str, str, str]] = {}  # key -> (table, field, placement)
         needed = required_columns(self.program, self.lowering.spec)
         for t, fields in needed.items():
             if t not in self.db:
                 continue
             ms = self.db[t]
-            cols[t] = {}
             sharded = t in self.lowering.mesh_rows
             for f in fields:
                 if f in ms.columns:
-                    host = ms.field(f)
-                    col = (
-                        _place_rows(np.asarray(host), self._rows_sharding, self.n_devices)
-                        if sharded
-                        else jnp.asarray(host)
-                    )
-                    cols[t][f] = col
-                    placed["sharded" if sharded else "single"] += col.nbytes
-        self.upload_bytes = {k: v for k, v in placed.items() if v}
+                    key = (ms.uid, f, self._rows_sharding if sharded else None)
+                    wanted[key] = functools.partial(self._place, ms, f, sharded)
+                    where[key] = (t, f, "sharded" if sharded else "single")
+        if self.resident is None:
+            got = {k: (place(), False) for k, place in wanted.items()}
+        else:
+            got = self.resident.fetch(wanted)
+        cols: Dict[str, Dict[str, jnp.ndarray]] = {t: {} for t in needed if t in self.db}
+        placed: Dict[str, int] = {}
+        resident: Dict[str, int] = {}
+        reads: Dict[str, int] = {}
+        for key, (col, hit) in got.items():
+            t, f, placement = where[key]
+            cols[t][f] = col
+            into = resident if hit else placed
+            into[placement] = into.get(placement, 0) + col.nbytes
+            result = "hit" if hit else "miss"
+            reads[result] = reads.get(result, 0) + 1
+        self.upload_bytes, self.resident_bytes = placed, resident
+        self.resident_reads = reads
         return cols
+
+    def _place(self, ms: Multiset, field: str, sharded: bool) -> jax.Array:
+        host = ms.field(field)
+        if sharded:
+            return _place_rows(np.asarray(host), self._rows_sharding, self.n_devices)
+        return jnp.asarray(host)
 
     def run(
         self, params: Optional[Dict[str, Any]] = None, *, tracer: Any = None

@@ -125,8 +125,10 @@ def auto_reformat(
     serve later queries that read the other fields (the paper's session
     model).  Callers that own the full workload pass persist_prune=True."""
     plan = plan_reformat(program, db)
-    if plan.worthwhile(expected_runs):
-        include = ("prune", "dict_encode", "compress_range") if persist_prune else (
-            "dict_encode", "compress_range")
+    include = ("prune", "dict_encode", "compress_range") if persist_prune else (
+        "dict_encode", "compress_range")
+    # a plan with nothing to apply keeps the database itself: a copy would
+    # look like a new database to its owner (and to its device columns)
+    if plan.worthwhile(expected_runs) and any(a.action in include for a in plan.actions):
         return apply_reformat(plan, db, include), plan
     return db, plan

@@ -246,6 +246,9 @@ class Database:
         # Session) on table replacement so that a swap to content the cheap
         # fingerprint cannot distinguish still lands in a fresh epoch.
         self._epoch_salt = int(epoch_salt)
+        # the device copies of its columns the engine keeps between queries
+        # (repro.backends.resident.ResidentColumns), made on first use
+        self.resident: Any = None
 
     def add(self, ms: Multiset) -> "Database":
         self.tables[ms.name] = ms

@@ -24,6 +24,7 @@ from dataclasses import dataclass
 from typing import Any, Deque, Dict, Iterator, List, Optional, Sequence, Tuple, Union
 
 from repro.analysis import IRVerificationError, LintWarning, lint_program, render_lint, verify_program
+from repro.backends.resident import resident_columns
 from repro.core.ir import Program
 from repro.core.passes import OptimizeOptions, OptimizeResult, optimize
 from repro.core.transforms import canonicalize_array_names
@@ -333,6 +334,9 @@ class Session:
         # parsed programs bind table schemas that may just have changed
         self._dispatch = {k: v for k, v in self._dispatch.items() if k[1] == self._epoch}
         self._programs.clear()
+        # device columns of a replaced, dropped or edited table must not be
+        # read again; which table changed is not known here, so all go
+        resident_columns(self.db).sync(self._epoch)
 
     def _revalidate(self) -> None:
         """``self.db`` is public and mutable (examples hand it to low-level
@@ -510,9 +514,9 @@ class Session:
         fingerprint inputs: a plan cached by one tenant must behave
         identically for every tenant, so sessions sharing a cache (a
         ``QueryServer``) all attach the same server-wide fault policy /
-        chunk executor / metrics registry, and re-attaching on every
-        dispatch keeps a cache-shared plan consistent with *this*
-        session's configuration."""
+        chunk executor / metrics registry / device-resident columns of
+        the shared database, and re-attaching on every dispatch keeps a
+        cache-shared plan consistent with *this* session's configuration."""
         if hasattr(plan, "fault"):
             plan.fault = self.fault
         if hasattr(plan, "chunk_executor"):
@@ -521,6 +525,10 @@ class Session:
             plan.metrics_registry = self.metrics_registry
         if hasattr(plan, "split"):
             plan.split = self._split_policy_for()
+        if hasattr(plan, "resident"):
+            # another session may have filled the store under an older epoch
+            plan.resident = resident_columns(self.db)
+            plan.resident.sync(self._epoch)
 
     def _split_policy_for(self) -> Any:
         """The mid-run skew-split policy attached to partitioned plans —
@@ -574,6 +582,7 @@ class Session:
         # reformatting persists across the session (amortization, §III-C1);
         # adopting the reformatted database moves the epoch forward
         if res.db is not self.db:
+            resident_columns(self.db).clear()  # no later plan of ours reads it
             self.db = res.db
             self._refresh_epoch()
         with self._memo_lock:
@@ -690,6 +699,10 @@ class Session:
             m.inc("mesh.collectives", op=op)
         for placement, n in getattr(res.plan, "upload_bytes", {}).items():
             m.inc("upload.bytes", n, placement=placement)
+        for placement, n in getattr(res.plan, "resident_bytes", {}).items():
+            m.inc("resident.bytes", n, placement=placement)
+        for result, n in getattr(res.plan, "resident_reads", {}).items():
+            m.inc("columns.resident", n, result=result)
         log = getattr(res.plan, "dispatch_log", None)
         if log:
             m.inc("chunks.dispatched", len(log))

@@ -58,6 +58,7 @@ def report(plan, rows, db, sql):
                           "devices": len(c.sharding.device_set)}
                       for f, c in placed.get("t", {}).items()},
         "upload_bytes": plan.upload_bytes,
+        "resident_bytes": plan.resident_bytes,
         "segreduce_path": plan.lowering.segreduce_path,
     }
 
@@ -72,9 +73,12 @@ out = {}
 for name, sql in QUERIES.items():
     s = Session(mesh=mesh, n_parts=4, revalidate="signature")
     s.register("t", **cols)
-    r = s.sql(sql)
-    r = s.sql(sql)  # a second run of the same plan
+    runs = []
+    for _ in range(2):  # the second run of the same plan reads its columns resident
+        r = s.sql(sql)
+        runs.append([r.plan.upload_bytes, r.plan.resident_bytes])
     out[name] = report(r.plan, r.rows, s.db, sql)
+    out[name]["runs"] = runs
     out[name]["parallel"] = r.decision.chosen.parallel
     out[name]["counters"] = s.metrics_registry.snapshot()["counters"]
 
@@ -84,7 +88,9 @@ for name in ("sum", "max"):
     sql = QUERIES[name]
     res = optimize(sql_to_forelem(sql, SCHEMA), s.db, OptimizeOptions(
         n_parts=4, agg_method="kernel", parallel_exec="shard_map", mesh=mesh))
-    out["kernel_" + name] = report(res.plan, res.plan.run()["R"], s.db, sql)
+    rows = res.plan.run()["R"]
+    out["kernel_" + name] = report(res.plan, rows, s.db, sql)
+    out["kernel_" + name]["runs"] = [[res.plan.upload_bytes, res.plan.resident_bytes]]
 
 # ... and over 3,000 keys, where each device contracts the sum on the MXU
 w = Session(mesh=mesh, n_parts=4, revalidate="signature")
@@ -92,18 +98,25 @@ w.register("t", **dict(cols, k=rng.integers(0, 3000, ROWS).astype(np.int32)))
 sql = QUERIES["sum"]
 res = optimize(sql_to_forelem(sql, SCHEMA), w.db, OptimizeOptions(
     n_parts=4, agg_method="kernel", parallel_exec="shard_map", mesh=mesh))
-out["kernel_sum_mxu"] = report(res.plan, res.plan.run()["R"], w.db, sql)
+rows = res.plan.run()["R"]
+out["kernel_sum_mxu"] = report(res.plan, rows, w.db, sql)
+out["kernel_sum_mxu"]["runs"] = [[res.plan.upload_bytes, res.plan.resident_bytes]]
 
 # a row count the mesh divides: no padding
 s = Session(mesh=mesh, n_parts=4, revalidate="signature")
 s.register("t", **{c: a[: 1 << 18] for c, a in cols.items()})
-r = s.sql(QUERIES["sum"])
+runs = []
+for _ in range(2):
+    r = s.sql(QUERIES["sum"])
+    runs.append([r.plan.upload_bytes, r.plan.resident_bytes])
 out["divisible"] = report(r.plan, r.rows, s.db, QUERIES["sum"])
+out["divisible"]["runs"] = runs
 print(json.dumps(out))
 """
 
 CASES = ("sum", "count", "min", "max", "filtered", "kernel_sum", "kernel_max", "divisible",
          "kernel_sum_mxu")
+IN_SESSION = ("sum", "count", "min", "max", "filtered", "divisible")
 ON_MESH = ("sum", "count", "min", "max", "kernel_sum", "kernel_max", "divisible",
            "kernel_sum_mxu")
 
@@ -132,7 +145,15 @@ def test_columns_placed_as_row_shards(mesh_report, case):
     assert r["shardings"] and all(
         s == {"mesh": True, "shards": [per] * 4, "devices": 4} for s in r["shardings"].values()
     ), r["shardings"]
-    assert r["upload_bytes"] == {"sharded": 4 * per * 4 * len(r["shardings"])}
+    want = {"sharded": 4 * per * 4 * len(r["shardings"])}
+    if case in IN_SESSION:
+        # the first run places the columns, later lookups read them resident
+        assert r["runs"] == [[want, {}], [{}, want]]
+        assert (r["upload_bytes"], r["resident_bytes"]) == ({}, want)
+    else:
+        # a plan run outside a session places them again on every run
+        assert r["runs"] == [[want, {}]]
+        assert (r["upload_bytes"], r["resident_bytes"]) == (want, {})
 
 
 def test_session_plans_shard_map(mesh_report):
@@ -141,7 +162,9 @@ def test_session_plans_shard_map(mesh_report):
     # table stays on one device, and the program combines nothing
     f = mesh_report["filtered"]
     assert f["mesh_rows"] == {} and f["collectives"] == [] and f["allreduce_operands"] == 0
-    assert f["upload_bytes"] == {"single": 3 * ((1 << 18) + 3) * 4}
+    want = {"single": 3 * ((1 << 18) + 3) * 4}
+    assert f["runs"] == [[want, {}], [{}, want]]
+    assert (f["upload_bytes"], f["resident_bytes"]) == ({}, want)
 
 
 @pytest.mark.parametrize("case", ON_MESH)
@@ -167,7 +190,14 @@ def test_kernel_path_of_each_launch_on_the_mesh(mesh_report, case, path):
 def test_counters_read_what_the_runs_placed_and_combined(mesh_report, case):
     r = mesh_report[case]
     c = r["counters"]
-    assert c["upload.bytes{placement=sharded}"] == 2 * r["upload_bytes"]["sharded"]
+    # two runs: the first placed the columns, the second read them resident
+    placed = r["runs"][0][0]["sharded"]
+    assert c["upload.bytes{placement=sharded}"] == placed
+    assert c["resident.bytes{placement=sharded}"] == placed
     assert "upload.bytes{placement=single}" not in c
+    assert "resident.bytes{placement=single}" not in c
+    n_cols = len(r["shardings"])
+    assert c["columns.resident{result=miss}"] == n_cols
+    assert c["columns.resident{result=hit}"] == n_cols
     assert c["mesh.collectives{op=psum}"] == 2 * r["collectives"].count("psum")
     assert c.get("mesh.collectives{op=pmax}", 0) == 2 * r["collectives"].count("pmax")
